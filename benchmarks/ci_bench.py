@@ -926,6 +926,9 @@ def main(argv=None) -> int:
             baseline = json.load(f)
         return check(ci, baseline, args.threshold)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     results = measure()
     with open(args.out, "w") as f:
         json.dump(results, f, indent=2, sort_keys=True)

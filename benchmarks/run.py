@@ -17,6 +17,9 @@ def main(argv=None) -> None:
     ap.add_argument("--only", type=str, default="")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import fig8_speedups, fig9_ablation, fig10_productivity
     from . import table3_flexibility, roofline_report
     from .common import DEFAULT_SCALE

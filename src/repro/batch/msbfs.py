@@ -9,8 +9,9 @@ queries into each lane word turns K frontier expansions into one:
   uint32 words — 64 sources ride one int64 lane word on x64-enabled
   builds, 32 per uint32 word otherwise);
 * one traversal step ORs every in-neighbor's frontier word into each
-  vertex — a segmented bitwise-OR over the CSC edge stream, computed with
-  one ``associative_scan`` (the shuffle network reduced to 1-bit lanes);
+  vertex — a segmented bitwise-OR over the dst-sorted CSC edge stream,
+  computed as a sorted segment max over the words unpacked to one byte
+  per query (the shuffle network reduced to 1-bit lanes);
 * newly reached bits record their BFS level, and the loop runs until every
   packed query has an empty frontier — one launch per level serves the
   whole batch, so the launch total is independent of K.
@@ -29,6 +30,7 @@ the fast path preserves the bit-identical batching contract.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -393,6 +395,49 @@ def _word_dtype():
     return jnp.uint32, 32
 
 
+def _lanes(words: jnp.ndarray, k: int) -> jnp.ndarray:
+    """[N, W] packed words -> [k, N] int32 0/1, one row per query lane.
+
+    Lane-major int32 keeps the TPU layout dense (a trailing axis of k
+    bytes would be padded to a full tile)."""
+    word_bits = jnp.iinfo(words.dtype).bits
+    lane = np.arange(k)
+    shift = jnp.asarray(lane % word_bits, words.dtype)[:, None]
+    return ((words.T[lane // word_bits] >> shift) & 1).astype(jnp.int32)
+
+
+def _pack(lanes: jnp.ndarray, n_words: int, wdt) -> jnp.ndarray:
+    """[k, N] 0/1 lanes -> [N, W] packed words (inverse of :func:`_lanes`)."""
+    k, n = lanes.shape
+    word_bits = jnp.iinfo(wdt).bits
+    lanes = jnp.pad(lanes.astype(wdt), ((0, n_words * word_bits - k), (0, 0)))
+    shifts = jnp.arange(word_bits, dtype=wdt)[None, :, None]
+    # distinct bits: the sum is their OR
+    words = (lanes.reshape(n_words, word_bits, n) << shifts).sum(axis=1, dtype=wdt)
+    return words.T
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def msbfs_step(frontier, seen, levels, depth, src, dst, *, k: int):
+    """One level of the packed traversal for ``k`` queries.
+
+    ``frontier``/``seen`` are [V, W] packed words, ``levels`` [K, V];
+    ``src``/``dst`` the CSC edge stream, ``dst`` ascending. Each vertex ORs
+    its in-neighbors' frontier words: unpacked to one lane per query, that
+    OR is a sorted segment max (the shuffle/reduce network on 1-bit
+    lanes), packed again to words.
+    """
+    n_v, n_words = frontier.shape
+    reach = jax.vmap(lambda lane: jax.ops.segment_max(
+        lane, dst, num_segments=n_v, indices_are_sorted=True,
+    ))(_lanes(frontier[src], k))  # [k, V]; int32 min where no in-edge
+    new = _pack(reach > 0, n_words, frontier.dtype) & ~seen
+    seen = seen | new
+    newly = _lanes(new, k) != 0  # [K, V]
+    levels = jnp.where(jnp.logical_and(newly, levels < 0), depth + 1, levels)
+    return new, seen, levels, jnp.any(new)
+
+
 def run_msbfs(be, plan: MSBFSPlan) -> None:
     """Execute the packed traversal on a BatchEngine and fill its state.
 
@@ -428,39 +473,12 @@ def run_msbfs(be, plan: MSBFSPlan) -> None:
     levels = jnp.asarray(levels0)
 
     if n_e > 0:
-        indeg = np.diff(indptr)
-        flags = np.zeros(n_e, bool)
-        flags[indptr[:-1][indeg > 0]] = True  # first in-edge of each vertex
-        has_in = indeg > 0
-        last = np.where(has_in, indptr[1:] - 1, 0)
-        csc_dev = jnp.asarray(np.asarray(csc_idx, np.int32))
-        flags_dev = jnp.asarray(flags)
-        last_dev = jnp.asarray(last.astype(np.int32))
-        has_in_dev = jnp.asarray(has_in)
-        shifts = jnp.arange(word_bits, dtype=wdt)
-
-        @jax.jit
-        def step(frontier, seen, levels, depth):
-            gathered = frontier[csc_dev]  # [E, W] packed frontier @ src
-
-            # segmented bitwise OR over the dst-sorted CSC edge stream:
-            # the shuffle/reduce network collapsed to 1-bit lanes
-            def comb(a, b):
-                fa, va = a
-                fb, vb = b
-                return fa | fb, jnp.where(fb[:, None], vb, va | vb)
-
-            _, ors = jax.lax.associative_scan(comb, (flags_dev, gathered))
-            reach = jnp.where(has_in_dev[:, None], ors[last_dev], wdt(0))
-            new = reach & ~seen
-            seen = seen | new
-            # unpack the newly-reached bits to record per-query levels
-            bits = ((new[:, :, None] >> shifts[None, None, :]) & wdt(1)) != 0
-            newly = bits.reshape(n_v, n_words * word_bits)[:, :k].T  # [K, V]
-            levels = jnp.where(
-                jnp.logical_and(newly, levels < 0), depth + 1, levels
-            )
-            return new, seen, levels, jnp.any(new)
+        # the CSC edge stream as arguments (a closed-over array would be
+        # baked into the executable as a constant): source and destination
+        # of every edge, destinations ascending
+        src_dev = jnp.asarray(np.asarray(csc_idx, np.int32))
+        dst_dev = jnp.asarray(
+            np.repeat(np.arange(n_v, dtype=np.int32), np.diff(indptr)))
 
     its = 0
     while True:
@@ -471,8 +489,8 @@ def run_msbfs(be, plan: MSBFSPlan) -> None:
         be.stats.edges_traversed += n_e
         if n_e == 0:
             break
-        frontier, seen, levels, any_new = step(
-            frontier, seen, levels, jnp.int32(its)
+        frontier, seen, levels, any_new = msbfs_step(
+            frontier, seen, levels, jnp.int32(its), src_dev, dst_dev, k=k
         )
         if not bool(any_new):
             break

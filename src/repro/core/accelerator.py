@@ -230,6 +230,10 @@ class KernelLibrary:
         self.target = target
         self.shape = shape
         self.warm_keys: set = set()
+        # what happened to the artifact's saved executables: loaded, or
+        # lowered again because the saved bytes could not be used
+        self.deserialized = 0
+        self.relowered = 0
         self._frontier_build = None
         self._generic: Dict[str, backend.GenericLoweredKernel] = {}
         for name, kern in module.kernels.items():
@@ -246,8 +250,9 @@ class KernelLibrary:
         """AOT-compile every kernel's full-stream executable.
 
         ``blobs`` maps kernel name -> a serialized executable payload from
-        a saved artifact; entries that deserialize are loaded instead of
-        recompiled, anything else transparently re-lowers.
+        a saved artifact (None where the artifact lists one that cannot be
+        used here); entries that deserialize are loaded instead of
+        recompiled, anything else re-lowers and counts in ``relowered``.
         """
         gb_specs = backend.gb_array_specs(self.shape.n_vertices, self.shape.n_edges)
         state_specs = _state_specs(self.module, self.shape)
@@ -263,6 +268,9 @@ class KernelLibrary:
                 compiled = _deserialize_executable(blob)
                 if compiled is not None:
                     mode = "aot-loaded"
+                    self.deserialized += 1
+            if compiled is None and blobs is not None and name in blobs:
+                self.relowered += 1
             if compiled is None:
                 compiled = g.jit_full.lower(
                     gb_specs, state_specs, scal_specs
@@ -543,7 +551,7 @@ class Accelerator:
     """
 
     def __init__(self, program: "Program", target: Target, shape: GraphShape,
-                 *, _blobs: Optional[Dict[str, bytes]] = None,
+                 *, _blobs: Optional[Dict[str, Optional[bytes]]] = None,
                  _profile: Optional[Dict[str, Any]] = None,
                  _tuned: Optional[Dict[str, Any]] = None):
         module = program.module
@@ -596,8 +604,20 @@ class Accelerator:
                 )
         self.lower_time_s = time.perf_counter() - t0
         self.binds = 0
+        self.serialized = 0  # executables written by the last save()
 
     # -- introspection -------------------------------------------------------
+    def executable_counts(self) -> Dict[str, int]:
+        """Kernels whose executables the last ``save`` serialized, that were
+        deserialized from an artifact, and that were re-lowered because an
+        artifact's executable could not be loaded."""
+        lib = self.library
+        return {
+            "serialized": self.serialized,
+            "deserialized": lib.deserialized if lib is not None else 0,
+            "relowered": lib.relowered if lib is not None else 0,
+        }
+
     def report(self) -> AcceleratorReport:
         """The HLS-resource-report analogue for this lowering."""
         module = self.program.module
@@ -738,6 +758,7 @@ class Accelerator:
         opts = self.program.options
         kernels_manifest: Dict[str, Dict[str, Any]] = {}
         exe_dir = os.path.join(path, "executables")
+        self.serialized = 0
         for plan in self._plans:
             entry: Dict[str, Any] = {"mode": plan.mode, "executable": None}
             if include_executables and self.library is not None:
@@ -752,6 +773,7 @@ class Accelerator:
                     with open(os.path.join(path, rel), "wb") as f:
                         f.write(payload)
                     entry["executable"] = rel
+                    self.serialized += 1
             kernels_manifest[plan.name] = entry
         manifest = {
             "format": ARTIFACT_FORMAT,
@@ -878,13 +900,17 @@ def load_accelerator(path: str) -> Accelerator:
             "different program fingerprint (source/options/toolchain drift); "
             "re-lower with program.lower(target, shape) and save again"
         )
-    blobs: Dict[str, bytes] = {}
-    if manifest.get("jax_version") == jax.__version__ and \
-            manifest.get("jax_backend") == jax.default_backend():
-        for name, entry in manifest.get("kernels", {}).items():
-            rel = entry.get("executable")
-            if rel:
-                # unreadable blob: re-lower this kernel
+    # kernel -> saved executable bytes; None marks one the artifact lists
+    # but this process cannot use (other JAX or backend, unreadable file),
+    # so compile_all counts its re-lowering
+    blobs: Dict[str, Optional[bytes]] = {}
+    same_runtime = (manifest.get("jax_version") == jax.__version__
+                    and manifest.get("jax_backend") == jax.default_backend())
+    for name, entry in manifest.get("kernels", {}).items():
+        rel = entry.get("executable")
+        if rel:
+            blobs[name] = None
+            if same_runtime:
                 with contextlib.suppress(OSError), \
                         open(os.path.join(path, rel), "rb") as f:
                     blobs[name] = f.read()

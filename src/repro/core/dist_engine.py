@@ -13,8 +13,9 @@ Vertices are range-partitioned across D devices; each edge lives on its
 3. local conflict-free reduce (sorted segment reduction) into the local
    destination-property slice — the URAM bank analogue.
 
-``DistGraph.push_step`` runs one superstep under ``shard_map``; it is the
-distribution layer used by the multi-device graph tests and benchmarks.
+:func:`partition_graph` buckets the edges and places each device's slice
+on it; :func:`make_expr_push_step` builds one superstep under ``shard_map``
+that takes the placed buckets as arguments.
 
 :class:`DistEngine` (bottom of this module) is the full execution backend
 built on top of it: it interprets the same host program as the local
@@ -27,18 +28,19 @@ with identical results.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import backend, fir, mir
 from .engine import Engine
 from .options import CompileOptions
+from .target import dist_mesh
 from .. import telemetry as tel
 from ..graph.storage import GraphData
 
@@ -56,10 +58,23 @@ class DistGraph:
     valid: np.ndarray  # [D, D, Emax]
     mesh: Mesh
     axis: str
+    _placed: Optional[Tuple[jax.Array, ...]] = field(default=None, repr=False)
 
     @property
     def slice_len(self) -> int:
         return self.n_vertices_padded // self.n_devices
+
+    def placed(self) -> Tuple[jax.Array, ...]:
+        """(src_local, dst_local, weight, valid) on the mesh, sharded on
+        the src-owner axis: device i holds the [1, D, Emax] slice of the
+        edges it owns. Placed once; every superstep takes them as arguments."""
+        if self._placed is None:
+            sharding = NamedSharding(self.mesh, P(self.axis))
+            self._placed = tuple(
+                jax.device_put(a, sharding)
+                for a in (self.src_local, self.dst_local, self.weight, self.valid)
+            )
+        return self._placed
 
 
 def partition_graph(g: GraphData, mesh: Mesh, axis: str = "data") -> DistGraph:
@@ -107,58 +122,23 @@ def make_push_step(
     dg: DistGraph,
     value_fn: Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray],
     reduce_op: str = "+",
-    combine: bool = True,
 ):
-    """Build the jitted superstep.
+    """Build a superstep over one property.
 
     value_fn(src_prop_vals, weights) -> update values (elementwise).
     Returns fn(prop [Vpad]) -> reduced updates [Vpad] (combined with the
     old property by the caller's vertex kernel).
     """
-    mesh, axis, sl = dg.mesh, dg.axis, dg.slice_len
-    src_l = jnp.asarray(dg.src_local)
-    dst_l = jnp.asarray(dg.dst_local)
-    w = jnp.asarray(dg.weight)
-    valid = jnp.asarray(dg.valid)
-    pspec = P(axis)
+    steps: Dict = {}
 
-    def local_step(prop_slice, src_b, dst_b, w_b, valid_b):
-        # [1, D, Emax] shards (leading src-owner axis sharded away)
-        src_b, dst_b, w_b, valid_b = (
-            src_b[0], dst_b[0], w_b[0], valid_b[0])
-        prop = prop_slice.reshape(-1)  # [sl]
-        vals = value_fn(prop[src_b], w_b)  # [D, Emax]
-        ident = _identity(reduce_op, vals.dtype)
-        vals = jnp.where(valid_b, vals, ident)
-        # shuffle across chips: route each dst-owner bucket to its device
-        vals_r = jax.lax.all_to_all(vals[None], axis, 1, 0, tiled=False)[:, 0]
-        dst_r = jax.lax.all_to_all(dst_b[None], axis, 1, 0, tiled=False)[:, 0]
-        valid_r = jax.lax.all_to_all(valid_b[None], axis, 1, 0, tiled=False)[:, 0]
-        # local conflict-free reduce (sorted segment reduction)
-        flat_v = jnp.where(valid_r, vals_r, ident).reshape(-1)
-        flat_d = jnp.where(valid_r, dst_r, sl).reshape(-1)
-        order = jnp.argsort(flat_d)
-        seg = {
-            "+": jax.ops.segment_sum,
-            "min": jax.ops.segment_min,
-            "max": jax.ops.segment_max,
-        }[reduce_op]
-        red = seg(flat_v[order], flat_d[order], sl + 1, indices_are_sorted=True)[:sl]
-        return red[None]
-
-    smapped = shard_map(
-        local_step,
-        mesh=mesh,
-        in_specs=(pspec, pspec, pspec, pspec, pspec),
-        out_specs=pspec,
-        check_rep=False,
-    )
-
-    @jax.jit
     def step(prop: jnp.ndarray) -> jnp.ndarray:
-        grid = prop.reshape(dg.n_devices, sl)
-        red = smapped(grid, src_l, dst_l, w, valid)
-        return red.reshape(-1)
+        dtype = jnp.dtype(prop.dtype)
+        if dtype not in steps:
+            steps[dtype] = make_expr_push_step(
+                dg, ["prop"], lambda env, w, s: value_fn(env["prop"], w),
+                None, reduce_op, dtype,
+            )
+        return steps[dtype]({"prop": prop}, {}, dg.placed())
 
     return step
 
@@ -277,10 +257,11 @@ def make_expr_push_step(
 ):
     """Build a jitted distributed superstep for one lowered edge kernel.
 
-    Like :func:`make_push_step`, but the per-edge value/condition read an
-    arbitrary set of src-gathered properties plus host scalars:
+    The per-edge value/condition read a set of src-gathered properties
+    plus host scalars; the edge buckets are arguments (``dg.placed()``),
+    so each device reads the slice it holds:
 
-        step(props: {name: [Vpad]}, scalars: {name: 0-d}) -> reduced [Vpad]
+        step(props: {name: [V]}, scalars: {name: 0-d}, buckets) -> reduced [Vpad]
 
     The returned array combines with the destination property via the
     kernel's reduce op (identity-filled where no edge contributed).
@@ -288,10 +269,6 @@ def make_expr_push_step(
     mesh, axis, sl = dg.mesh, dg.axis, dg.slice_len
     d = dg.n_devices
     vpad = dg.n_vertices_padded
-    src_l = jnp.asarray(dg.src_local)
-    dst_l = jnp.asarray(dg.dst_local)
-    w = jnp.asarray(dg.weight)
-    valid = jnp.asarray(dg.valid)
     pspec = P(axis)
     seg = {
         "+": jax.ops.segment_sum,
@@ -325,17 +302,18 @@ def make_expr_push_step(
         mesh=mesh,
         in_specs=(pspec, P(), pspec, pspec, pspec, pspec),
         out_specs=pspec,
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
-    def step(props: Dict[str, jnp.ndarray], scalars: Dict[str, jnp.ndarray]):
+    def step(props: Dict[str, jnp.ndarray], scalars: Dict[str, jnp.ndarray],
+             buckets: Tuple[jax.Array, ...]):
         grids = {}
         for n in src_props:
             arr = props[n]
             padded = jnp.zeros((vpad,), arr.dtype).at[: arr.shape[0]].set(arr)
             grids[n] = padded.reshape(d, sl)
-        red = smapped(grids, scalars, src_l, dst_l, w, valid)
+        red = smapped(grids, scalars, *buckets)
         return red.reshape(-1)
 
     return step
@@ -366,7 +344,7 @@ class DistEngine(Engine):
         super().__init__(module, graph, options, argv=argv, target=target,
                          library=library)
         if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),), (axis,))
+            mesh = dist_mesh(jax.device_count(), axis)
         self.mesh = mesh
         self.axis = axis
         self._dist_graph: Optional[DistGraph] = None
@@ -387,6 +365,16 @@ class DistEngine(Engine):
         if self._dist_graph is None:
             self._dist_graph = partition_graph(self.graph, self.mesh, self.axis)
         return self._dist_graph
+
+    def bucket_placement(self) -> Dict[int, List[int]]:
+        """Device id -> the src-owner slices of the edge buckets it holds
+        (empty until the first distributed superstep partitions the graph)."""
+        if self._dist_graph is None:
+            return {}
+        out: Dict[int, List[int]] = {}
+        for shard in self._dist_graph.placed()[0].addressable_shards:
+            out.setdefault(shard.device.id, []).append(shard.index[0].start or 0)
+        return out
 
     # -- per-kernel distributed lowering ------------------------------------
     def _dist_kernel(self, name: str) -> Optional[tuple]:
@@ -434,7 +422,8 @@ class DistEngine(Engine):
                 edges=self.graph.n_edges,
             )
         with sp:
-            red = self._timed_call(("dist", name), step, props, scalars)[
+            red = self._timed_call(("dist", name), step, props, scalars,
+                                   self._partitioned().placed())[
                 : self.graph.n_vertices
             ]
         cur = self.state[out_prop]
@@ -469,7 +458,6 @@ class DistEngine(Engine):
         elif kern is not None and kern.kind is mir.KernelKind.EDGE:
             entry = self._dist_kernel(name)
             if entry is not None:
-                step_fn = self._batched_superstep(entry)
                 n_edges = self.graph.n_edges
 
                 def bump(stats):
@@ -477,19 +465,27 @@ class DistEngine(Engine):
                     stats.edges_traversed += n_edges
 
                 bl = self._batched[name] = BatchedLaunch(
-                    fn=jax.jit(step_fn), bump_stats=bump
+                    fn=self._with_buckets(jax.jit(self._batched_superstep(entry))),
+                    bump_stats=bump,
                 )
                 return bl
         return super().batched_runner(name)
 
+    def _with_buckets(self, fn: Callable) -> Callable:
+        """Adapt ``fn(state, scalars, buckets)`` to the BatchedLaunch
+        signature, passing the placed edge buckets as arguments."""
+        buckets = self._partitioned().placed()
+        return lambda state, scalars: fn(state, scalars, buckets)
+
     def _batched_superstep(self, entry: tuple):
-        """fn(state, scalars) -> {out_prop: combined} over a leading K axis."""
+        """fn(state, scalars, buckets) -> {out_prop: combined} over a
+        leading K axis (the buckets are shared by every query)."""
         step, out_prop, op, src_props = entry
-        vstep = jax.vmap(step)
+        vstep = jax.vmap(step, in_axes=(0, 0, None))
         n_v = self.graph.n_vertices
 
-        def run(state, scalars):
-            red = vstep({p: state[p] for p in src_props}, scalars)[:, :n_v]
+        def run(state, scalars, buckets):
+            red = vstep({p: state[p] for p in src_props}, scalars, buckets)[:, :n_v]
             cur = state[out_prop]
             return {out_prop: backend.combine(op, cur, red.astype(cur.dtype))}
 
@@ -512,18 +508,19 @@ class DistEngine(Engine):
                 n_dist += 1
             else:
                 module, options, gb = self.module, self.options, self.gb
-                stage_fns.append(jax.vmap(
+                vstage = jax.vmap(
                     lambda s, sc, stage=stage: backend._exec_kernel_full(
                         module, stage, options, gb, s, sc)
-                ))
+                )
+                stage_fns.append(lambda s, sc, buckets, f=vstage: f(s, sc))
                 if stage.kind is mir.KernelKind.EDGE:
                     n_local_edges += 1
 
-        def run(state, scalars):
+        def run(state, scalars, buckets):
             cur = dict(state)
             out = {}
             for fn in stage_fns:
-                upd = fn(cur, scalars)
+                upd = fn(cur, scalars, buckets)
                 cur.update(upd)
                 out.update(upd)
             return out
@@ -535,7 +532,7 @@ class DistEngine(Engine):
             stats.full_launches += len(stage_fns) - n_dist
             stats.edges_traversed += n_edges * (n_dist + n_local_edges)
 
-        return BatchedLaunch(fn=jax.jit(run), bump_stats=bump)
+        return BatchedLaunch(fn=self._with_buckets(jax.jit(run)), bump_stats=bump)
 
     # -- launch override -----------------------------------------------------
     def launch(self, name: str):

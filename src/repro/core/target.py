@@ -118,11 +118,9 @@ class Target:
         on a real TPU would silently deoptimize device runs — so auto
         means "interpret unless jax is actually backed by a TPU".
         """
-        if self.interpret is not None:
-            return self.interpret
-        import jax
+        from ..kernels.ops import resolve_interpret
 
-        return jax.default_backend() != "tpu"
+        return resolve_interpret(self.interpret)
 
     @property
     def backend_name(self) -> str:
@@ -136,7 +134,7 @@ class Target:
         import jax
 
         n = self.n_devices or jax.device_count()
-        return jax.make_mesh((n,), (self.axis,))
+        return dist_mesh(n, self.axis)
 
     def auto_partitions(self, n_vertices: int) -> int:
         """Resolve the dst-range partition count for a vertex count."""
@@ -202,6 +200,20 @@ class Target:
             if getattr(self, name)
         ) or "none"
         return f"{self.kind}{mesh} [{opts}] parts={self.n_partitions or 'auto'}"
+
+
+def dist_mesh(n_devices: int, axis: str):
+    """The 1-D device mesh distributed targets run on.
+
+    Its axis is ``Auto``: the engine combines a superstep's sharded output
+    with single-device state in plain jnp ops, which ``Explicit`` axes
+    (``jax.make_mesh``'s default) reject with a sharding type error.
+    """
+    import jax
+
+    return jax.make_mesh(
+        (n_devices,), (axis,), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
 
 #: Default Target: the single source of truth for substrate defaults — the

@@ -16,12 +16,14 @@ Grid = (P, T) with clamped tile index maps exactly as in shuffle_reduce.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ops import resolve_interpret
 from .ref import _identity
 
 
@@ -86,7 +88,7 @@ def edge_stream_call(
     reduce_op: str = "min",
     u: int = 512,
     et: int = 1024,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     n = src_vals.shape[0]
     et = min(et, max(128, 1 << (max(1, n) - 1).bit_length()))
@@ -144,6 +146,6 @@ def edge_stream_call(
         functools.partial(_kernel, apply_op=apply_op, reduce_op=reduce_op, u=u, et=et),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, n_out_pad), src_vals.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(tile_lo, tile_hi, sv[None, :], w[None, :], ds[None, :], ac[None, :])
     return out[0, :n_out]

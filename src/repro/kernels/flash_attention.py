@@ -14,11 +14,14 @@ lives in VMEM scratch and persists across the k-block inner loop.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ops import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -82,7 +85,7 @@ def flash_attention_call(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     b, h, lq, dh = q.shape
     hkv = k.shape[1]
@@ -118,6 +121,6 @@ def flash_attention_call(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qp, kp, vp)
     return out.reshape(b, h, lq_pad, dh)[:, :, :lq, :]

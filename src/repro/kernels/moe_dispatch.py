@@ -18,11 +18,14 @@ Contract:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ops import resolve_interpret
 
 
 def _kernel(off_ref, size_ref, tok_ref, out_ref, *, block_c: int, d: int):
@@ -43,7 +46,7 @@ def moe_gather_call(
     capacity: int,
     *,
     block_c: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     t, d = tokens_sorted.shape
     e = group_offsets.shape[0]
@@ -73,7 +76,7 @@ def moe_gather_call(
         functools.partial(_kernel, block_c=block_c, d=d),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, capacity, d), tokens_sorted.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(
         group_offsets.astype(jnp.int32),
         group_sizes.astype(jnp.int32),

@@ -7,9 +7,22 @@ contracts (tile multiples, sorted streams).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Resolve a Pallas ``interpret`` argument; ``None`` means auto.
+
+    Auto interprets on hosts without a TPU (CPU CI) and compiles the kernel
+    on a TPU, so a caller that leaves the argument out never runs the
+    interpreter on the chip without asking for it.
+    """
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jnp.ndarray, n: int, value) -> jnp.ndarray:
@@ -26,7 +39,7 @@ def shuffle_reduce(
     n_out: int,
     op: str = "+",
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     u: int = 512,
     et: int = 1024,
 ) -> jnp.ndarray:
@@ -58,7 +71,7 @@ def shuffle_reduce_batched(
     n_out: int,
     op: str = "+",
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     u: int = 512,
     et: int = 1024,
 ) -> jnp.ndarray:
@@ -95,7 +108,7 @@ def edge_stream_batched(
     apply_op: str = "add",
     reduce_op: str = "min",
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Batched fused edge pipeline: ``[K, E]`` gathered operands in ONE kernel.
 
@@ -131,7 +144,7 @@ def edge_stream(
     apply_op: str = "add",
     reduce_op: str = "min",
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Fused gather->apply->shuffle->reduce edge pipeline (paper Fig. 4)."""
     from .edge_stream import edge_stream_call
@@ -149,7 +162,7 @@ def moe_gather(
     group_sizes: jnp.ndarray,
     capacity: int,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Capacity-binned expert gather via the Pallas dispatch kernel."""
     from .moe_dispatch import moe_gather_call
@@ -171,7 +184,7 @@ def flash_attention(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Blocked online-softmax attention (beyond-paper LM hot-spot kernel)."""
     from .flash_attention import flash_attention_call

@@ -24,12 +24,14 @@ Validated against ``ref.shuffle_reduce_ref`` in interpret mode (CPU).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ops import resolve_interpret
 from . import ref
 
 NEG = {"min": "max", "max": "min"}
@@ -99,7 +101,7 @@ def shuffle_reduce_sorted(
     op: str = "+",
     u: int = 512,
     et: int = 1024,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Reduce sorted (idx, val) update streams into ``n_out`` bins.
 
@@ -147,6 +149,6 @@ def shuffle_reduce_sorted(
         functools.partial(_kernel, op=op, u=u, et=et),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, n_out_pad), vals.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(tile_lo, tile_hi, idx_sorted[None, :], vals[None, :])
     return out[0]

@@ -46,6 +46,7 @@ import numpy as np
 
 from ..configs import ARCH_IDS, get_config, smoke_config
 from ..models import Model
+from .compile_cache import enable_compile_cache
 
 
 def generate(model: Model, params, prompts: jnp.ndarray, gen_len: int,
@@ -385,6 +386,7 @@ def main(argv=None):
     ap.add_argument("--edges", type=int, default=16000)
     ap.add_argument("--backend", choices=("local", "distributed"), default="local")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.graph is not None:
         if args.batch is None:

@@ -379,6 +379,10 @@ class ArtifactRegistry:
     # -- introspection / lifecycle -------------------------------------------
     def info(self) -> Dict[str, Any]:
         with self._lock:
+            executables = {"serialized": 0, "deserialized": 0, "relowered": 0}
+            for acc in self._accelerators.values():
+                for k, n in acc.executable_counts().items():
+                    executables[k] += n
             return {
                 "store_dir": self.store_dir,
                 "resident": len(self._residents),
@@ -387,6 +391,9 @@ class ArtifactRegistry:
                 "max_accelerators": self.max_accelerators,
                 "lowerings": self.lowerings,
                 "negative_entries": len(self._negative),
+                # over the cached accelerators: see
+                # Accelerator.executable_counts
+                "executables": executables,
             }
 
     def close(self) -> None:

@@ -256,6 +256,28 @@ def test_loaded_artifact_prefers_stored_executables(graph, tmp_path):
                           loaded.bind(graph).run(root=7))
 
 
+def test_executable_counts_report_relowering(graph, tmp_path):
+    """Every kernel's executable is counted through save and load, and one
+    that cannot be loaded is counted as re-lowered, not hidden."""
+    import os
+
+    acc = repro.compile(sources.BFS_ECP).lower(graph=graph)
+    n = len(acc.report().kernels)
+    path = acc.save(str(tmp_path / "bfs"))
+    assert acc.executable_counts()["serialized"] == n
+    loaded = repro.load_accelerator(path)
+    assert loaded.executable_counts() == {
+        "serialized": 0, "deserialized": n, "relowered": 0}
+    exe_dir = os.path.join(path, "executables")
+    with open(os.path.join(exe_dir, sorted(os.listdir(exe_dir))[0]), "wb") as f:
+        f.write(b"not an executable")
+    damaged = repro.load_accelerator(path)
+    assert damaged.executable_counts() == {
+        "serialized": 0, "deserialized": n - 1, "relowered": 1}
+    _assert_results_equal(acc.bind(graph).run(root=7),
+                          damaged.bind(graph).run(root=7))
+
+
 def test_save_without_executables_relowers(graph, tmp_path):
     acc = repro.compile(sources.WCC).lower(graph=graph)
     path = acc.save(str(tmp_path / "wcc"), include_executables=False)

@@ -86,7 +86,7 @@ def test_compressed_allreduce(subproc):
         """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.distributed.compression import compressed_allreduce
 mesh = jax.make_mesh((8,), ("data",))
 x = np.random.default_rng(0).normal(size=(8, 64)).astype(np.float32)

@@ -1,7 +1,7 @@
 """Graph substrate property tests (storage, partitioning, generators)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.graph import generators
 from repro.graph.datasets import TABLE_II, make_dataset

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
 
@@ -50,6 +50,18 @@ def test_shuffle_reduce_empty_bins():
     vals = jnp.asarray([1.0, 2.0, 3.0], jnp.float32)
     out = np.asarray(ops.shuffle_reduce(vals, idx, 5, "min"))
     assert out[2] == 1.0 and np.isinf(out[0]) and np.isinf(out[4])
+
+
+def test_interpret_default_follows_the_backend():
+    """Left out, ``interpret`` compiles on a TPU and interprets elsewhere,
+    by the same rule as ``Target.interpret_effective``."""
+    from repro.core.target import Target
+
+    auto = jax.default_backend() != "tpu"
+    assert ops.resolve_interpret(None) is auto
+    assert Target().interpret_effective is auto
+    assert ops.resolve_interpret(True) is True
+    assert ops.resolve_interpret(False) is False
 
 
 # --------------------------------------------------------------------------

@@ -22,6 +22,7 @@ import threading
 
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 import repro
 from repro.algorithms import sources
@@ -31,9 +32,6 @@ from repro.core.passes import analyze_incremental
 from repro.graph import generators
 from repro.graph.storage import GraphData, GraphDelta, GraphUpdateError
 from repro.streaming import StreamingSession
-
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings
-from _hypothesis_compat import strategies as st
 
 
 def _bucketed(n_vertices=300, n_edges=1800, *, weighted=False, seed=1):
@@ -432,6 +430,3 @@ def test_random_deltas_preserve_equivalence(seed, n_deltas, k):
     finally:
         ss.close()
 
-
-def test_hypothesis_compat_flag_is_boolean():
-    assert HAVE_HYPOTHESIS in (True, False)
