@@ -9,10 +9,11 @@ The workflow is:
     ... compile / lower / bind / run  # stages emit nested spans
     tracer.export_chrome("trace.json")  # open in chrome://tracing
 
-Every traced run also feeds the accelerator's persistent profile:
-`accelerator.report().profile` accumulates per-kernel wall time across
-runs and is saved with the artifact, so a warm-started process inherits
-the profiling baseline of the process that built it.
+Launch spans time the enqueue of a kernel, not its run on the device; the
+`run` span ends when the answer is on the host, and its `host_sync`
+children hold the time the host waited on the device. Under
+`jax.profiler.start_trace` each live span is also a `gt.<name>` event of
+the profiler's trace, beside the device's kernels (`jit_gt_<kernel>_...`).
 """
 import os
 import tempfile
@@ -41,34 +42,31 @@ def main():
     print(f"spans in this run: {trace['span_count']}, "
           f"wall: {trace['total_s'] * 1e3:.1f}ms")
 
-    # 2. top-5 hottest kernels by traced wall time
+    # 2. top-5 kernels by launch-span time: the host's enqueue (and a
+    #    first touch's compile), not the kernel's time on the device
     launches = {
         name[len("launch:"):]: agg
         for name, agg in trace["spans"].items()
         if name.startswith("launch:")
     }
-    print("\n=== top-5 hottest kernels ===")
+    print("\n=== top-5 kernels by launch-span (enqueue) time ===")
     ranked = sorted(launches.items(), key=lambda kv: -kv[1]["total_s"])
     for name, agg in ranked[:5]:
         print(f"  {name:>24}: {agg['total_s'] * 1e3:8.1f}ms "
               f"over {agg['count']} launch(es) "
               f"(max {agg['max_s'] * 1e3:.1f}ms)")
 
-    # 3. the accelerator's profile section accumulates across runs and is
-    #    persisted with the artifact (warm starts inherit it)
-    session.run(root=17)
-    report = acc.report()
-    print(f"\nprofile: {report.profile['runs']} traced run(s) folded into "
-          f"accelerator {acc.fingerprint[:12]}")
+    # 3. where the run waited on the device: its host syncs, by reason
+    syncs = [s for s in telemetry.get().spans() if s.name == "host_sync"]
+    print(f"\n{result.stats.host_syncs} host syncs, "
+          f"{result.stats.host_sync_bytes} bytes read back")
+    for reason in sorted({s.attrs["reason"] for s in syncs}):
+        mine = [s for s in syncs if s.attrs["reason"] == reason]
+        print(f"  {reason:>14}: {len(mine)} sync(s), "
+              f"{sum(s.duration_s for s in mine) * 1e3:.1f}ms")
 
+    # 4. export the whole session as a Chrome trace
     with tempfile.TemporaryDirectory() as d:
-        acc.save(f"{d}/bfs")
-        loaded = repro.load_accelerator(f"{d}/bfs")
-        inherited = loaded.report().profile
-        print(f"warm-started profile baseline: {inherited['runs']} run(s), "
-              f"{len(inherited['spans'])} span name(s) inherited")
-
-        # 4. export the whole session as a Chrome trace
         out = os.path.join(d, "trace.json")
         n = telemetry.get().export_chrome(out)
         size = os.path.getsize(out)

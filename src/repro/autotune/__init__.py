@@ -27,9 +27,9 @@ trials**:
   (``None`` estimates from backends without XLA cost analysis degrade
   to lane-count fallbacks — a missing estimate never crashes a trial).
 * *Measured trials* lower each candidate, bind it to the probe graph,
-  and take the best-of-``reps`` objective: the sum of ``launch:<kernel>``
-  span aggregates from :mod:`repro.telemetry` (wall time as fallback
-  when tracing yields no launch spans). A candidate whose first
+  and take the best-of-``reps`` objective: the ``run`` span's total from
+  :mod:`repro.telemetry`, which ends when the answer is on the host (wall
+  time as fallback when tracing yields no run span). A candidate whose first
   repetition already exceeds ``margin`` x the incumbent is *dominated*
   and dropped without finishing its repetitions.
 
@@ -82,9 +82,9 @@ SEARCHED_KNOBS: Tuple[str, ...] = (
     "burst", "cache", "shuffle", "compact_frontier",
 )
 
-#: Objective identifier recorded in every TunedConfig: the per-run sum of
-#: ``launch:<kernel>`` span totals from repro.telemetry.
-OBJECTIVE = "launch_total_s"
+#: Objective identifier recorded in every TunedConfig: the ``run`` span's
+#: total from repro.telemetry, which ends when the answer is on the host.
+OBJECTIVE = "run_total_s"
 
 
 def default_tuning_dir() -> str:
@@ -513,12 +513,10 @@ class AutoTuner:
     @staticmethod
     def _objective_from_trace(trace: Optional[Dict[str, Any]],
                               wall_s: float) -> float:
-        """Sum of ``launch:<kernel>`` span totals, else the wall time."""
+        """The ``run`` span's total (device work included), else the wall
+        time."""
         spans = (trace or {}).get("spans") or {}
-        total = sum(
-            v.get("total_s", 0.0)
-            for name, v in spans.items() if name.startswith("launch:")
-        )
+        total = spans.get("run", {}).get("total_s", 0.0)
         return total if total > 0.0 else wall_s
 
     def _measure(self, program, target: Target, shape: GraphShape, graph,
@@ -591,7 +589,7 @@ class AutoTuner:
     def _search(self, program, graph, params, base: Target,
                 shape: GraphShape, mir_fp: str, bucket: GraphShape,
                 cands: List[Target], pruned: List[str]) -> TuneReport:
-        # trials need launch-span objectives: enable tracing for the
+        # trials need run-span objectives: enable tracing for the
         # search, restore the caller's state after (an already-enabled
         # tracer is left untouched — enable() is idempotent)
         was_enabled = tel.enabled()

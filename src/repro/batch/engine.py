@@ -38,6 +38,7 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,6 +51,8 @@ from ..core.engine import (
     EngineResult,
     EngineStats,
     count_launch,
+    host_read,
+    timed_first_call,
 )
 
 
@@ -147,20 +150,24 @@ class BatchEngine:
         self._reset(param_sets)
         tr = tel.get()
         root_ctx = None
+        # as in Engine.run: the run ends when its answers are on the host
         if tr.enabled:
             with tr.span("run", engine=type(self).__name__,
                          batch_size=k) as sp:
                 self._run_host(keys, k)
+                props = self._read_back()
                 sp.set(launches=self.stats.total_launches,
-                       msbfs=self.MSBFS_NAME in self.stats.kernel_launches)
+                       msbfs=self.MSBFS_NAME in self.stats.kernel_launches,
+                       host_syncs=self.stats.host_syncs)
             root_ctx = sp.context()
         else:
             self._run_host(keys, k)
+            props = self._read_back()
         self.stats.wall_time_s = time.perf_counter() - t0
         self.stats.run_time_s = max(
             0.0, self.stats.wall_time_s - self.stats.compile_time_s
         )
-        results = self._finalize()
+        results = self._finalize(props)
         if root_ctx is not None:
             trace = tr.summarize(root=root_ctx)
             for r in results:
@@ -239,17 +246,10 @@ class BatchEngine:
         # first-touch (cold) timing: every distinct batch size K is its own
         # XLA trace; share the inner engine's warm-key registry so the
         # compile/run split stays consistent across run modes
-        warm = self.engine._warm_keys
-        key = ("batched", name, self.batch_size)
-        if key in warm:
-            updates = bl.fn(self.state, scalars)
-        else:
-            t0 = time.perf_counter()
-            try:
-                updates = bl.fn(self.state, scalars)
-            finally:
-                self.stats.compile_time_s += time.perf_counter() - t0
-                warm.add(key)
+        updates = timed_first_call(
+            self.stats, self.engine._warm_keys,
+            ("batched", name, self.batch_size), bl.fn, self.state, scalars,
+        )
         bl.bump_stats(self.stats)
         self._merge(updates, mask)
 
@@ -289,6 +289,8 @@ class BatchEngine:
     # masked host interpretation
     # ------------------------------------------------------------------
     def _truthy(self, v) -> np.ndarray:
+        if isinstance(v, jax.Array):
+            v = host_read(self.stats, v, "cond")
         return np.broadcast_to(np.asarray(v) != 0, (self.batch_size,))
 
     def _exec_block(self, body: List[fir.Stmt], mask: np.ndarray) -> None:
@@ -400,7 +402,7 @@ class BatchEngine:
             if isinstance(base, fir.Ident) and base.name in self.module.properties:
                 cols = self._xlate(base.name, self._eval(e.index, mask))
                 rows = np.arange(self.batch_size)
-                return np.asarray(self.state[base.name][rows, cols])
+                return host_read(self.stats, self.state[base.name][rows, cols], "scalar")
             idx = self._eval(e.index, mask)
             if isinstance(idx, np.ndarray):
                 uniq = np.unique(idx)
@@ -471,11 +473,12 @@ class BatchEngine:
     # ------------------------------------------------------------------
     # result splitting
     # ------------------------------------------------------------------
-    def _finalize(self) -> List[EngineResult]:
+    def _read_back(self) -> Dict[str, np.ndarray]:
+        """Every property on the host, ``[K, n]``, in original vertex ids."""
         eng = self.engine
         props: Dict[str, np.ndarray] = {}
         for p in self.module.properties.values():
-            arr = np.asarray(self.state[p.name])
+            arr = host_read(self.stats, self.state[p.name], "result")
             if (
                 eng.old2new is not None
                 and not p.is_edge
@@ -484,7 +487,11 @@ class BatchEngine:
                 arr = arr[:, eng.old2new]
             props[p.name] = arr
         if WEIGHT_KEY in self.state:
-            props["weight"] = np.asarray(self.state[WEIGHT_KEY])
+            props["weight"] = host_read(self.stats, self.state[WEIGHT_KEY],
+                                         "result")
+        return props
+
+    def _finalize(self, props: Dict[str, np.ndarray]) -> List[EngineResult]:
         results = []
         for k in range(self.batch_size):
             henv: Dict[str, Any] = {}

@@ -41,9 +41,8 @@ import hashlib
 import json
 import os
 import pickle
-import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple, TYPE_CHECKING
 
 import jax
@@ -323,8 +322,8 @@ class KernelLibrary:
         if g is None:
             raise AcceleratorError(f"{name!r} is not a device kernel")
         if g.jit_batched is None:
-            g.jit_batched = jax.jit(
-                jax.vmap(g.raw_full, in_axes=(None, 0, 0))
+            g.jit_batched = backend.gt_jit(
+                jax.vmap(g.raw_full, in_axes=(None, 0, 0)), name + "_batched"
             )
         gba = backend.split_gb_arrays(gb)
         jit_batched = g.jit_batched
@@ -446,10 +445,6 @@ class AcceleratorReport:
     #: determinism certificate from repro.analysis (deterministic /
     #: reduction-deterministic / racy) — also stored in artifact manifests
     determinism: str = "unknown"
-    #: profiling baseline from traced runs (repro.telemetry): ``{"runs": N,
-    #: "spans": {name: {count, total_s, max_s}}}`` — persisted with the
-    #: artifact manifest so warm-started processes inherit it
-    profile: Dict[str, Any] = field(default_factory=dict)
 
     @property
     def total_flops_per_launch_set(self) -> float:
@@ -466,19 +461,6 @@ class AcceleratorReport:
             f"/{len(self.kernels)} kernels AOT)",
             f"  determinism: {self.determinism}",
         ]
-        if self.profile.get("runs"):
-            hot = sorted(
-                ((k, v) for k, v in self.profile.get("spans", {}).items()
-                 if k.startswith("launch:")),
-                key=lambda kv: -kv[1].get("total_s", 0.0),
-            )[:5]
-            hottest = ", ".join(
-                f"{k.split(':', 1)[1]} {v['total_s']:.3f}s" for k, v in hot
-            )
-            lines.append(
-                f"  profile: {self.profile['runs']} traced run(s)"
-                + (f"; hottest: {hottest}" if hottest else "")
-            )
         for k in self.kernels:
             extra = f" = {' -> '.join(k.stages)}" if k.stages else ""
             cost = f"{k.flops:.3g} flops" if k.flops else "?"
@@ -552,7 +534,6 @@ class Accelerator:
 
     def __init__(self, program: "Program", target: Target, shape: GraphShape,
                  *, _blobs: Optional[Dict[str, Optional[bytes]]] = None,
-                 _profile: Optional[Dict[str, Any]] = None,
                  _tuned: Optional[Dict[str, Any]] = None):
         module = program.module
         if module.graph.weighted and not shape.weighted:
@@ -573,14 +554,6 @@ class Accelerator:
         self.tuned: Optional[Dict[str, Any]] = (
             dict(_tuned) if _tuned else None
         )
-        # profiling baseline fed by traced runs (repro.telemetry): per span
-        # name -> {count, total_s, max_s}; persisted in the artifact
-        # manifest so warm-started processes inherit it
-        self._profile_lock = threading.Lock()
-        self._profile: Dict[str, Dict[str, float]] = dict(
-            (_profile or {}).get("spans", {})
-        )
-        self.profile_runs = int((_profile or {}).get("runs", 0))
         tr = tel.get()
         sp = tr.span(
             "lower", fingerprint=self.fingerprint[:16], target=target.kind,
@@ -638,40 +611,12 @@ class Accelerator:
             live_buffer_peak_bytes=peak, lower_time_s=self.lower_time_s,
             pass_report=tuple(module.pass_report),
             determinism=self._determinism(),
-            profile=self.profile(),
         )
 
     def _determinism(self) -> str:
         from ..analysis import determinism_certificate
 
         return determinism_certificate(self.program.module)
-
-    # -- profiling baseline (repro.telemetry) --------------------------------
-    def record_profile(self, trace: Optional[Dict[str, Any]]) -> None:
-        """Fold one traced run's summary (``EngineResult.trace``) into the
-        accelerator's profile. Sessions call this after every traced run;
-        untraced runs pass None and cost one branch."""
-        if not trace:
-            return
-        spans = trace.get("spans") or {}
-        with self._profile_lock:
-            self.profile_runs += 1
-            for name, a in spans.items():
-                cur = self._profile.setdefault(
-                    name, {"count": 0, "total_s": 0.0, "max_s": 0.0}
-                )
-                cur["count"] += a.get("count", 0)
-                cur["total_s"] = round(cur["total_s"] + a.get("total_s", 0.0), 6)
-                cur["max_s"] = max(cur["max_s"], a.get("max_s", 0.0))
-
-    def profile(self) -> Dict[str, Any]:
-        """The accumulated profiling baseline: ``{"runs": N, "spans":
-        {name: {count, total_s, max_s}}}`` (empty until a traced run)."""
-        with self._profile_lock:
-            return {
-                "runs": self.profile_runs,
-                "spans": {k: dict(v) for k, v in self._profile.items()},
-            }
 
     def __repr__(self) -> str:
         return (
@@ -792,7 +737,6 @@ class Accelerator:
             "pass_report": list(self.program.module.pass_report),
             "determinism": self._determinism(),
             "kernels": kernels_manifest,
-            "profile": self.profile(),
             "tuned": self.tuned,
         }
         with open(os.path.join(path, "program.gt"), "w") as f:
@@ -916,8 +860,6 @@ def load_accelerator(path: str) -> Accelerator:
                     blobs[name] = f.read()
     target = Target.from_dict(manifest["target"])
     shape = GraphShape(**manifest["shape"])
-    profile = manifest.get("profile")
     tuned = manifest.get("tuned")
     return Accelerator(program, target, shape, _blobs=blobs or None,
-                       _profile=profile if isinstance(profile, dict) else None,
                        _tuned=tuned if isinstance(tuned, dict) else None)

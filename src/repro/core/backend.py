@@ -518,6 +518,14 @@ def _obj_name(e: fir.Expr) -> str:
 # ---------------------------------------------------------------------------
 
 
+def gt_jit(fn: Callable, name: str, **jit_kwargs) -> Callable:
+    """``jax.jit`` of ``fn`` under the name ``gt_<name>``, so its HLO module
+    and every device op of it in a profiler trace read ``jit_gt_<name>``
+    (``jit_gt_EdgeTraversal_full``, ``jit_gt_frontier_build``, ...)."""
+    fn.__name__ = fn.__qualname__ = "gt_" + name
+    return jax.jit(fn, **jit_kwargs)
+
+
 @dataclass
 class LoweredKernel:
     """A device kernel lowered against a concrete graph + target."""
@@ -558,9 +566,6 @@ def make_frontier_builder(n_vertices: int, n_edges: int, weighted: bool):
     binds.
     """
 
-    import functools
-
-    @functools.partial(jax.jit, static_argnames=("pad_v", "pad_e"))
     def build(deg, starts, csr_indices, csr_eids, mask, weights, pad_v, pad_e):
         (act,) = jnp.nonzero(mask, size=pad_v, fill_value=n_vertices)  # O(V)
         vok = act < n_vertices
@@ -580,7 +585,7 @@ def make_frontier_builder(n_vertices: int, n_edges: int, weighted: bool):
         w = weights[eid] if weighted else jnp.zeros((pad_e,), jnp.float32)
         return src, dst, w, eid, valid
 
-    return build
+    return gt_jit(build, "frontier_build", static_argnames=("pad_v", "pad_e"))
 
 
 def gb_array_specs(n_vertices: int, n_edges: int) -> Dict[str, Any]:
@@ -720,7 +725,8 @@ def lower_pipeline(
         return out
 
     return LoweredKernel(
-        pipeline.name, mir.KernelKind.PIPELINE, run_full=jax.jit(run_full),
+        pipeline.name, mir.KernelKind.PIPELINE,
+        run_full=gt_jit(run_full, pipeline.name + "_full"),
         trace_full=run_full,
     )
 
@@ -740,7 +746,7 @@ def lower_kernel_batched(lowered: LoweredKernel) -> Callable:
     of ``run_full`` — because a compiled executable cannot be traced.
     """
     fn = lowered.trace_full if lowered.trace_full is not None else lowered.run_full
-    return jax.jit(jax.vmap(fn))
+    return gt_jit(jax.vmap(fn), lowered.name + "_batched")
 
 
 def lower_kernel(
@@ -776,8 +782,8 @@ def lower_kernel(
 
         return LoweredKernel(
             kernel.name, kernel.kind,
-            run_full=jax.jit(run_full),
-            run_subset=jax.jit(run_subset),
+            run_full=gt_jit(run_full, kernel.name + "_full"),
+            run_subset=gt_jit(run_subset, kernel.name + "_subset"),
             frontier=kernel.frontier,
             trace_full=run_full,
         )
@@ -795,8 +801,9 @@ def lower_kernel(
 
     return LoweredKernel(
         kernel.name, kernel.kind,
-        run_full=jax.jit(run_full),
-        run_subset=jax.jit(run_subset) if not kernel.has_neighbor_loop else None,
+        run_full=gt_jit(run_full, kernel.name + "_full"),
+        run_subset=(gt_jit(run_subset, kernel.name + "_subset")
+                    if not kernel.has_neighbor_loop else None),
         frontier=kernel.frontier,
         trace_full=run_full,
     )
@@ -860,7 +867,8 @@ def lower_kernel_generic(
             return out
 
         return GenericLoweredKernel(
-            kernel.name, mir.KernelKind.PIPELINE, raw_full, jax.jit(raw_full)
+            kernel.name, mir.KernelKind.PIPELINE, raw_full,
+            gt_jit(raw_full, kernel.name + "_full"),
         )
 
     if kernel.kind is mir.KernelKind.EDGE:
@@ -888,8 +896,10 @@ def lower_kernel_generic(
             return out
 
         return GenericLoweredKernel(
-            kernel.name, kernel.kind, raw_full, jax.jit(raw_full),
-            jit_subset=jax.jit(raw_subset), frontier=kernel.frontier,
+            kernel.name, kernel.kind, raw_full,
+            gt_jit(raw_full, kernel.name + "_full"),
+            jit_subset=gt_jit(raw_subset, kernel.name + "_subset"),
+            frontier=kernel.frontier,
         )
 
     # vertex kernel
@@ -907,7 +917,9 @@ def lower_kernel_generic(
         return ex.commit()
 
     return GenericLoweredKernel(
-        kernel.name, kernel.kind, raw_full, jax.jit(raw_full),
-        jit_subset=jax.jit(raw_subset) if not kernel.has_neighbor_loop else None,
+        kernel.name, kernel.kind, raw_full,
+        gt_jit(raw_full, kernel.name + "_full"),
+        jit_subset=(gt_jit(raw_subset, kernel.name + "_subset")
+                    if not kernel.has_neighbor_loop else None),
         frontier=kernel.frontier,
     )

@@ -254,8 +254,10 @@ def make_expr_push_step(
     cond_fn: Optional[Callable],
     reduce_op: str,
     out_dtype,
+    name: str = "push_step",
 ):
-    """Build a jitted distributed superstep for one lowered edge kernel.
+    """Build a jitted distributed superstep for one lowered edge kernel
+    (named ``jit_gt_<name>`` on the device).
 
     The per-edge value/condition read a set of src-gathered properties
     plus host scalars; the edge buckets are arguments (``dg.placed()``),
@@ -305,7 +307,6 @@ def make_expr_push_step(
         check_vma=False,
     )
 
-    @jax.jit
     def step(props: Dict[str, jnp.ndarray], scalars: Dict[str, jnp.ndarray],
              buckets: Tuple[jax.Array, ...]):
         grids = {}
@@ -316,7 +317,7 @@ def make_expr_push_step(
         red = smapped(grids, scalars, *buckets)
         return red.reshape(-1)
 
-    return step
+    return backend.gt_jit(step, name)
 
 
 class DistEngine(Engine):
@@ -396,7 +397,8 @@ class DistEngine(Engine):
             )
             out_dtype = self.state[out_prop].dtype
             step = make_expr_push_step(
-                self._partitioned(), sorted(src_props), val_fn, cond_fn, op, out_dtype
+                self._partitioned(), sorted(src_props), val_fn, cond_fn, op,
+                out_dtype, name=name + "_superstep",
             )
             entry = (step, out_prop, op, sorted(src_props))
         except _NotDistributable:
@@ -465,7 +467,8 @@ class DistEngine(Engine):
                     stats.edges_traversed += n_edges
 
                 bl = self._batched[name] = BatchedLaunch(
-                    fn=self._with_buckets(jax.jit(self._batched_superstep(entry))),
+                    fn=self._with_buckets(backend.gt_jit(
+                        self._batched_superstep(entry), name + "_batched")),
                     bump_stats=bump,
                 )
                 return bl
@@ -532,7 +535,10 @@ class DistEngine(Engine):
             stats.full_launches += len(stage_fns) - n_dist
             stats.edges_traversed += n_edges * (n_dist + n_local_edges)
 
-        return BatchedLaunch(fn=self._with_buckets(jax.jit(run)), bump_stats=bump)
+        return BatchedLaunch(
+            fn=self._with_buckets(backend.gt_jit(run, kern.name + "_batched")),
+            bump_stats=bump,
+        )
 
     # -- launch override -----------------------------------------------------
     def launch(self, name: str):

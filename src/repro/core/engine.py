@@ -74,6 +74,10 @@ class EngineStats:
     # how many queries this run answered (1 = plain sequential run; K > 1 =
     # one batched run whose launches served K parameter bindings at once)
     batch_size: int = 1
+    # device->host reads the interpreter waited on (Engine.host_read), and
+    # the bytes they brought back; per batch, like the launch counters
+    host_syncs: int = 0
+    host_sync_bytes: int = 0
 
     @property
     def total_launches(self) -> int:
@@ -94,6 +98,38 @@ def count_launch(stats: EngineStats, module: mir.Module, name: str) -> None:
     if parts:
         stats.fused_launches += 1
         stats.launches_saved += len(parts) - 1
+
+
+def host_read(stats: EngineStats, value, reason: str) -> np.ndarray:
+    """Read a device value back to the host, counted in ``stats``.
+
+    The one place the interpreters wait on the device: every read bumps
+    ``host_syncs`` / ``host_sync_bytes``, and under a recording tracer it
+    is a ``host_sync`` span with ``reason`` (``scalar``, ``cond``,
+    ``frontier_mask``, ``result``) and ``bytes``."""
+    nbytes = int(value.nbytes)
+    stats.host_syncs += 1
+    stats.host_sync_bytes += nbytes
+    tr = tel.get()
+    if not tr.enabled:
+        return np.asarray(value)
+    with tr.span("host_sync", reason=reason, bytes=nbytes):
+        return np.asarray(value)
+
+
+def timed_first_call(stats: EngineStats, warm: set, key, fn, *args):
+    """Call ``fn``; attribute a first-touch (cold) call's wall time to
+    ``stats.compile_time_s`` and mark ``key`` warm. Shared by the
+    sequential, batched and MS-BFS launch paths, so the compile/run split
+    reads the same in every run mode."""
+    if key in warm:
+        return fn(*args)
+    t0 = time.perf_counter()
+    try:
+        return fn(*args)
+    finally:
+        stats.compile_time_s += time.perf_counter() - t0
+        warm.add(key)
 
 
 @dataclass
@@ -310,18 +346,14 @@ class Engine:
         return self._lowered[name]
 
     def _timed_call(self, key, fn, *args):
-        """Call ``fn``; attribute a first-touch (cold) call's wall time to
-        ``stats.compile_time_s``. The warm-key registry survives reset()
-        (kernels stay compiled) and is shared across binds when a kernel
-        library backs this engine."""
-        if key in self._warm_keys:
-            return fn(*args)
-        t0 = time.perf_counter()
-        try:
-            return fn(*args)
-        finally:
-            self.stats.compile_time_s += time.perf_counter() - t0
-            self._warm_keys.add(key)
+        """First-touch timing (:func:`timed_first_call`). The warm-key
+        registry survives reset() (kernels stay compiled) and is shared
+        across binds when a kernel library backs this engine."""
+        return timed_first_call(self.stats, self._warm_keys, key, fn, *args)
+
+    def host_read(self, value, reason: str) -> np.ndarray:
+        """Read a device value back to the host (:func:`host_read`)."""
+        return host_read(self.stats, value, reason)
 
     def _kernel_scalars(self, name: str) -> Dict[str, jnp.ndarray]:
         k = self.module.kernels[name]
@@ -514,7 +546,7 @@ class Engine:
                     kern.src_param,
                     kern.vertex_param,
                 ):
-                    return np.asarray(self.state[prop])
+                    return self.host_read(self.state[prop], "frontier_mask")
                 raise EngineError("frontier cond must index by src/v")
             if isinstance(e, fir.BinOp):
                 a, b = ev(e.lhs), ev(e.rhs)
@@ -549,24 +581,38 @@ class Engine:
         assert host is not None
         tr = tel.get()
         root_ctx = None
+        # the run ends when its answer is on the host: the readback is part
+        # of it, so wall_time_s (and the run span) cover the device's work
         if tr.enabled:
             with tr.span("run", engine=type(self).__name__,
                          target=self.target.kind, batch_size=1) as sp:
                 self._exec_host_block(host.main.body)
+                props = self._read_back()
                 sp.set(launches=self.stats.total_launches,
                        compacted=self.stats.compacted_launches,
                        full=self.stats.full_launches,
-                       supersteps=self.stats.dist_supersteps)
+                       supersteps=self.stats.dist_supersteps,
+                       host_syncs=self.stats.host_syncs)
             root_ctx = sp.context()
         else:
             self._exec_host_block(host.main.body)
+            props = self._read_back()
         self.stats.wall_time_s = time.perf_counter() - t0
         self.stats.run_time_s = max(
             0.0, self.stats.wall_time_s - self.stats.compile_time_s
         )
+        result = EngineResult(
+            properties=props, host_env=dict(self.host_env), stats=self.stats
+        )
+        if root_ctx is not None:
+            result.trace = tr.summarize(root=root_ctx)
+        return result
+
+    def _read_back(self) -> Dict[str, np.ndarray]:
+        """Every property on the host, in original vertex ids."""
         props = {}
         for p in self.module.properties.values():
-            arr = np.asarray(self.state[p.name])
+            arr = self.host_read(self.state[p.name], "result")
             if (
                 self.old2new is not None
                 and not p.is_edge
@@ -575,13 +621,8 @@ class Engine:
                 arr = arr[self.old2new]
             props[p.name] = arr
         if WEIGHT_KEY in self.state:
-            props["weight"] = np.asarray(self.state[WEIGHT_KEY])
-        result = EngineResult(
-            properties=props, host_env=dict(self.host_env), stats=self.stats
-        )
-        if root_ctx is not None:
-            result.trace = tr.summarize(root=root_ctx)
-        return result
+            props["weight"] = self.host_read(self.state[WEIGHT_KEY], "result")
+        return props
 
     def _exec_host_block(self, body: List[fir.Stmt]):
         for st in body:
@@ -658,8 +699,9 @@ class Engine:
             raise EngineError("host for loops are not part of the grammar")
         raise EngineError(f"unsupported host statement {type(st).__name__}")
 
-    @staticmethod
-    def _truthy(v) -> bool:
+    def _truthy(self, v) -> bool:
+        if isinstance(v, jax.Array):
+            v = self.host_read(v, "cond")
         return bool(np.asarray(v).item() if hasattr(v, "item") else v)
 
     def _eval_host(self, e: Optional[fir.Expr]):
@@ -683,7 +725,7 @@ class Engine:
             base = e.base
             if isinstance(base, fir.Ident) and base.name in self.module.properties:
                 i = self._xlate(base.name, int(self._eval_host(e.index)))
-                return np.asarray(self.state[base.name][i]).item()
+                return self.host_read(self.state[base.name][i], "scalar").item()
             if isinstance(base, fir.Ident) and base.name == "argv":
                 return self.argv[int(self._eval_host(e.index))]
             seq = self._eval_host(base)

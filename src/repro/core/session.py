@@ -190,7 +190,7 @@ class Session:
             program, graph, argv=argv, **backend_opts
         )
         self.runs = 0
-        # set by Accelerator.bind: traced runs feed its profiling baseline
+        # set by Accelerator.bind: the artifact this session was bound from
         self.accelerator = None
         self._batch_session: Optional["BatchSession"] = None
         self._batch_unsupported = False
@@ -205,8 +205,6 @@ class Session:
             self.backend.apply_params(coerced)
             result = self.backend.execute()
             self.runs += 1
-        if result.trace is not None and self.accelerator is not None:
-            self.accelerator.record_profile(result.trace)
         return result
 
     def run_many(self, param_sets: Sequence[Dict[str, Any]],
@@ -352,7 +350,7 @@ class BatchSession:
         self.max_batch = max_batch
         self.runs = 0
         self.queries = 0
-        # set by Accelerator.bind_batch: traced runs feed its profile
+        # set by Accelerator.bind_batch: the artifact this session was bound from
         self.accelerator = None
         self._lock = threading.Lock()
 
@@ -379,11 +377,6 @@ class BatchSession:
                 out.extend(self.engine.run_batch(chunk))
                 self.runs += 1
                 self.queries += len(chunk)
-        if out and out[-1].trace is not None and self.accelerator is not None:
-            # one summary per chunk; chunks share the run's trace shape
-            seen = {id(r.trace): r.trace for r in out if r.trace is not None}
-            for trace in seen.values():
-                self.accelerator.record_profile(trace)
         return out
 
     def refresh_graph(self, graph: Optional[GraphData] = None) -> None:

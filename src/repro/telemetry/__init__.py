@@ -10,16 +10,29 @@ span            where                                          attrs
 ``compile``     :func:`repro.compile` (front-end + passes)     frontend, cache_hit, fingerprint
 ``lower``       ``Program.lower`` / ``Accelerator.__init__``   fingerprint, target, bucket
 ``bind``        ``Accelerator.bind`` / session construction    fingerprint, n_vertices, n_edges
-``run``         one ``Engine``/``BatchEngine`` execution       launches, batch K, version
-``launch:<k>``  one device-kernel launch                       mode, direction, frontier occupancy
+``run``         one ``Engine``/``BatchEngine`` execution,      launches, batch K, host_syncs
+                until its answer is on the host
+``launch:<k>``  one device-kernel launch: the host's enqueue,  mode, direction, frontier occupancy
+                not the kernel's time on the device
+``host_sync``   one device->host read the interpreter waits    reason (scalar, cond,
+                on (``Engine.host_read``)                      frontier_mask, result), bytes
 ``superstep``   one distributed shuffle superstep              kernel, devices, shuffle elements
 ``update``      ``StreamingSession.update``                    delta sizes, version
 ``repair``      incremental recomputation of a cached result   program, version
 ``schedule``    ``GraphService.submit`` admission              tenant, label, deadline
-``queue_wait``  submit -> scheduler pickup                     tenant
+``queue_wait``  submit -> scheduler pickup (recorded after)    tenant
 ``batch_form``  scheduler fill-wait while forming a batch      batch K
+                (recorded after)
 ``execute``     scheduler running a formed batch               tenant, label, batch K
 =============== ============================================= =========
+
+While a recording tracer is installed, each live span is also a
+``jax.profiler.TraceAnnotation`` named ``gt.<span name>`` (``gt.run``,
+``gt.launch:EdgeTraversal``, ``gt.host_sync``), so a profiler trace puts
+the program's phases on the device trace's clock beside the kernels,
+which are named ``jit_gt_<kernel>_full|subset|batched``. Spans recorded
+after the fact (``queue_wait``, ``batch_form``, a straggler's
+``execute``) are in the tracer only.
 
 Usage::
 

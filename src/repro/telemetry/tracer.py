@@ -28,7 +28,11 @@ Design constraints, in priority order:
 
 Durations use ``time.perf_counter()`` throughout; the tracer records one
 wall-clock anchor at construction so exporters can place spans on an
-absolute timeline without per-span ``time.time()`` calls.
+absolute timeline without per-span ``time.time()`` calls. Each live span
+of a recording tracer also opens a ``jax.profiler.TraceAnnotation`` named
+``gt.<span name>``, so a profiler trace holds the program's spans on the
+device trace's own clock; spans recorded after the fact
+(:meth:`Tracer.record_span`) are in the tracer only.
 Head-based **trace sampling** keeps always-on tracing cheap at high QPS:
 ``Tracer(sample=0.1)`` (or ``repro.telemetry.enable(sample=0.1)``) makes
 the keep-or-drop decision once per *root* span — a dropped root installs
@@ -66,6 +70,20 @@ _SAMPLED_OUT = (-1, -1)
 # aggregate under "other" (guards against unbounded label cardinality)
 _MAX_HIST_NAMES = 256
 
+# profiler annotations of live spans: "gt." + the span name, nothing else,
+# so names stay stable in the trace whatever the span's attributes
+ANNOTATION_PREFIX = "gt."
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` for one live span
+    (imported here: the null tracer never touches jax.profiler)."""
+    from jax.profiler import TraceAnnotation
+
+    ann = TraceAnnotation(ANNOTATION_PREFIX + name)
+    ann.__enter__()
+    return ann
+
 
 def _new_histogram():
     # deferred: repro.serving imports repro.core which imports telemetry
@@ -97,7 +115,7 @@ class Span:
 
     __slots__ = (
         "name", "span_id", "parent_id", "trace_id", "t_start", "t_end",
-        "attrs", "thread_id", "thread_name", "_tracer", "_token",
+        "attrs", "thread_id", "thread_name", "_tracer", "_token", "_ann",
     )
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
@@ -115,6 +133,7 @@ class Span:
         self.thread_name = th.name
         self._tracer = tracer
         self._token = None
+        self._ann = None
 
     @property
     def duration_s(self) -> float:
@@ -129,11 +148,15 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _CURRENT.set((self.trace_id, self.span_id))
+        self._ann = _annotation(self.name)
         self.t_start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.t_end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
         if self._token is not None:
@@ -209,6 +232,9 @@ class Tracer:
         self.max_spans = max_spans
         self._lock = threading.Lock()
         self._spans: List[Span] = []
+        # the retained spans again, by trace id: a per-run summary reads
+        # its own trace, never the whole buffer
+        self._by_trace: Dict[int, List[Span]] = {}
         self.dropped = 0
         self._id_lock = threading.Lock()
         self._next_id = 1
@@ -300,6 +326,7 @@ class Tracer:
         with self._lock:
             if len(self._spans) < self.max_spans:
                 self._spans.append(span)
+                self._by_trace.setdefault(span.trace_id, []).append(span)
             else:
                 self.dropped += 1
             key = span.name if (
@@ -318,6 +345,7 @@ class Tracer:
     def reset(self) -> None:
         with self._lock:
             self._spans.clear()
+            self._by_trace.clear()
             self._hist.clear()
             self.dropped = 0
             self.sampled_out = 0
@@ -335,25 +363,20 @@ class Tracer:
         retained span contributes. Returns ``{"spans": {name: {count,
         total_s, max_s}}, "total_s", "span_count", "dropped"}``.
         """
-        spans = self.spans()
-        if root is not None:
-            keep = {root.span_id}
-            grew = True
+        if root is None:
+            spans = self.spans()
+        else:
+            with self._lock:
+                in_trace = list(self._by_trace.get(root.trace_id, ()))
             by_parent: Dict[Optional[int], List[Span]] = {}
-            for s in spans:
+            for s in in_trace:
                 by_parent.setdefault(s.parent_id, []).append(s)
+            spans = [s for s in in_trace if s.span_id == root.span_id]
             frontier = [root.span_id]
-            while grew and frontier:
-                grew = False
-                nxt: List[int] = []
-                for pid in frontier:
-                    for s in by_parent.get(pid, ()):
-                        if s.span_id not in keep:
-                            keep.add(s.span_id)
-                            nxt.append(s.span_id)
-                            grew = True
-                frontier = nxt
-            spans = [s for s in spans if s.span_id in keep]
+            while frontier:
+                kids = [s for pid in frontier for s in by_parent.get(pid, ())]
+                spans.extend(kids)
+                frontier = [s.span_id for s in kids]
         agg: Dict[str, Dict[str, float]] = {}
         for s in spans:
             a = agg.setdefault(s.name, {"count": 0, "total_s": 0.0, "max_s": 0.0})

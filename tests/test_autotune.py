@@ -429,8 +429,11 @@ def test_cost_score_tolerates_none_estimates():
 def test_objective_falls_back_to_wall_time():
     assert AutoTuner._objective_from_trace(None, 0.5) == 0.5
     assert AutoTuner._objective_from_trace({"spans": {}}, 0.5) == 0.5
+    # launch spans time the enqueue: only the run span counts
     trace = {"spans": {"launch:k": {"total_s": 0.2}, "run": {"total_s": 9.0}}}
-    assert AutoTuner._objective_from_trace(trace, 0.5) == pytest.approx(0.2)
+    assert AutoTuner._objective_from_trace(trace, 0.5) == pytest.approx(9.0)
+    launches_only = {"spans": {"launch:k": {"total_s": 0.2}}}
+    assert AutoTuner._objective_from_trace(launches_only, 0.5) == 0.5
 
 
 def test_tuner_parameter_validation():

@@ -1,11 +1,13 @@
-"""End-to-end tracing tests: span trees, exporters, profile persistence.
+"""End-to-end tracing tests: span trees, exporters, host syncs, profiler.
 
 The tracer is process-global, so every test runs under the ``tracer``
 fixture, which guarantees a fresh enabled tracer on entry and a swap
 back to the null tracer on exit (pytest-xdist shards by test, and within
 one worker tests are sequential, so no cross-test bleed).
 """
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
@@ -15,6 +17,7 @@ from repro import telemetry
 from repro.algorithms import sources
 from repro.core.program import clear_program_cache
 from repro.graph import generators
+from repro.telemetry.tracer import ANNOTATION_PREFIX
 
 
 @pytest.fixture
@@ -237,34 +240,122 @@ def test_service_request_span_trees_and_stats(tracer):
 
 
 # --------------------------------------------------------------------------
-# profile persistence
+# host syncs and the profiler's clock
 # --------------------------------------------------------------------------
 
 
-def test_profile_persists_with_artifact(tracer, tmp_path):
-    clear_program_cache()
-    g = generators.power_law(300, 2400, seed=3)
+def _descendants(tr, root_span):
+    by_parent = {}
+    for s in tr.spans():
+        by_parent.setdefault(s.parent_id, []).append(s)
+    out, stack = [], [root_span.span_id]
+    while stack:
+        for child in by_parent.get(stack.pop(), []):
+            out.append(child)
+            stack.append(child.span_id)
+    return out
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["engine", "batch"])
+def test_run_span_holds_its_host_syncs(tracer, batched):
+    g = generators.power_law(300, 2400, seed=2)
     program = repro.compile(sources.BFS_ECP)
-    acc = program.lower(repro.Target(), shape=repro.GraphShape.of(g))
-    session = acc.bind(g)
-    session.run(root=1)
-    session.run(root=2)
+    if batched:
+        result = program.bind_batch(g, msbfs=False).run_many(
+            [{"root": 0}, {"root": 3}])[0]
+    else:
+        result = program.bind(g).run(root=3)
+    (run_span,) = [s for s in tracer.spans() if s.name == "run"]
+    syncs = [s for s in _descendants(tracer, run_span)
+             if s.name == "host_sync"]
+    # every counted sync is a span under the run, the readback among them
+    assert len(syncs) == result.stats.host_syncs
+    assert run_span.attrs["host_syncs"] == result.stats.host_syncs
+    assert sum(s.attrs["bytes"] for s in syncs) == result.stats.host_sync_bytes
+    results = [s for s in syncs if s.attrs["reason"] == "result"]
+    assert len(results) == 4
+    assert all(s.parent_id == run_span.span_id for s in results)
+    assert max(s.t_end for s in syncs) <= run_span.t_end
+    reasons = {s.attrs["reason"] for s in syncs}
+    assert reasons <= {"scalar", "cond", "frontier_mask", "result"}
+    assert "scalar" in reasons
+    assert ("frontier_mask" in reasons) is not batched
+    assert result.trace["spans"]["host_sync"]["count"] == len(syncs)
 
-    prof = acc.report().profile
-    assert prof["runs"] == 2
-    assert any(name.startswith("launch:") for name in prof["spans"])
-    for agg in prof["spans"].values():
-        assert agg["count"] > 0 and agg["total_s"] >= 0
 
-    acc.save(str(tmp_path / "bfs"))
-    loaded = repro.load_accelerator(str(tmp_path / "bfs"))
-    inherited = loaded.report().profile
-    assert inherited["runs"] == 2
-    assert inherited["spans"].keys() == prof["spans"].keys()
-    # warm runs keep accumulating on top of the inherited baseline
-    loaded.bind(g).run(root=3)
-    assert loaded.report().profile["runs"] == 3
-    assert "traced run(s)" in loaded.report().describe()
+def _xplane_host_events(trace_dir):
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                             recursive=True))
+    assert paths, f"no xplane under {trace_dir}"
+    events = []
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(ANNOTATION_PREFIX):
+                    events.append((e.name, e.duration_ns * 1e-9))
+    return events
+
+
+def test_profiler_trace_mirrors_live_spans(tracer, tmp_path):
+    import jax
+
+    g = generators.power_law(300, 2400, seed=2)
+    session = repro.compile(sources.BFS_ECP).bind(g)
+    session.run(root=0)  # compile outside the trace
+    tracer.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        session.run(root=3)
+        # recorded after the fact: in the tracer only
+        tracer.record_span("queue_wait", 0.0, 1.0)
+    finally:
+        jax.profiler.stop_trace()
+    events = _xplane_host_events(str(tmp_path))
+    live = [s for s in tracer.spans() if s.name != "queue_wait"]
+    want = sorted(ANNOTATION_PREFIX + s.name for s in live)
+    assert sorted(name for name, _ in events) == want
+    assert "gt.queue_wait" not in {name for name, _ in events}
+    names = {name for name, _ in events}
+    assert {"gt.run", "gt.host_sync"} <= names
+    assert any(n.startswith("gt.launch:") for n in names)
+    # same intervals on the profiler's clock: each span's duration matches
+    # an annotation of its name within a millisecond
+    by_name = {}
+    for name, dur in events:
+        by_name.setdefault(name, []).append(dur)
+    for name, durs in by_name.items():
+        mine = sorted(s.duration_s for s in live
+                      if ANNOTATION_PREFIX + s.name == name)
+        assert np.allclose(sorted(durs), mine, rtol=0, atol=1e-3), name
+
+
+def test_summarize_reads_only_its_own_trace():
+    tr = telemetry.tracer.Tracer()
+    with tr.span("other"):
+        with tr.span("launch:x"):
+            pass
+    with tr.span("run") as root:
+        with tr.span("launch:k"):
+            with tr.span("host_sync"):
+                pass
+        with tr.span("host_sync"):
+            pass
+    tr.record_span("queue_wait", 0.0, 1.0, parent=root.context())
+    with tr.span("later"):
+        pass
+    summary = tr.summarize(root=root.context())
+    assert summary["spans"].keys() == {"run", "launch:k", "host_sync",
+                                       "queue_wait"}
+    assert summary["spans"]["host_sync"]["count"] == 2
+    assert summary["span_count"] == 5
+    # the whole buffer is still summarized without a root
+    assert tr.summarize()["span_count"] == 8
+    tr.reset()
+    assert tr.summarize(root=root.context())["span_count"] == 0
 
 
 def test_result_trace_none_when_untraced():
