@@ -62,7 +62,9 @@ if TYPE_CHECKING:  # pragma: no cover - import cycle guards
 # format 2: logical_counts joined GB_ARRAY_KEYS (size() reads unpadded
 # counts), changing the AOT executable signature — format-1 artifacts are
 # rejected and re-lowered
-ARTIFACT_FORMAT = 2
+# format 3: dst_sort_perm left GB_ARRAY_KEYS (shuffle targets stream edges
+# in dst order), changing the signature again
+ARTIFACT_FORMAT = 3
 MANIFEST_NAME = "manifest.json"
 
 
@@ -307,7 +309,7 @@ class KernelLibrary:
 
         return backend.LoweredKernel(
             name, g.kind, run_full=run_full, run_subset=run_subset,
-            frontier=g.frontier, trace_full=trace_full,
+            frontier=g.frontier, trace_full=trace_full, presorted=g.presorted,
         )
 
     def batched_for(self, name: str, gb: Dict[str, Any]):

@@ -10,9 +10,12 @@ module becomes a composable JAX/Pallas stage:
                      prefix block (VMEM-resident on TPU)
     Edge/Vertex Op-> the user function body, evaluated lane-parallel by the
                      expression evaluator below (VPU/MXU code on TPU)
-    Shuffle+Reduce-> precomputed dst-sort permutation + sorted segment
-                     reduction (conflict-free by construction); optionally
-                     routed through the Pallas ``shuffle_reduce`` kernel
+    Shuffle+Reduce-> under ``shuffle`` the Burst Read plan streams edges in
+                     destination order (burst order, stably sorted by dst),
+                     so a dst-lane write commits as a sorted segment
+                     reduction with no runtime permutation (conflict-free
+                     by construction); optionally routed through the
+                     Pallas ``shuffle_reduce`` kernel
     Burst Write   -> sequential lane-aligned writes (plain vector ops)
 
 Semantics notes (mirror the paper's pipeline transforms):
@@ -89,10 +92,13 @@ def apply_scatter(
     mask: Optional[jnp.ndarray],
     op: Optional[str],
     *,
-    sort_perm: Optional[jnp.ndarray] = None,
+    dst_sorted: bool = False,
     options: CompileOptions,
 ) -> jnp.ndarray:
-    """Commit one scattered write group — the Shuffle/RAW/Reduce stage."""
+    """Commit one scattered write group — the Shuffle/RAW/Reduce stage.
+
+    ``dst_sorted``: ``idx`` is the dst lane of a dst-ordered stream, so it
+    is already sorted and the reduction needs no routing step."""
     n = prop_arr.shape[0]
     vals = vals.astype(prop_arr.dtype) if vals.dtype != prop_arr.dtype else vals
     if op is None:
@@ -106,7 +112,7 @@ def apply_scatter(
             pos = jnp.arange(n_lanes, dtype=jnp.int32)
             if mask is not None:
                 pos = jnp.where(mask, pos, -1)
-            last = jax.ops.segment_max(pos, idx, n)
+            last = jax.ops.segment_max(pos, idx, n, indices_are_sorted=dst_sorted)
             written = last >= 0
             chosen = vals[jnp.clip(last, 0, max(n_lanes - 1, 0))]
             return jnp.where(written, chosen, prop_arr)
@@ -127,9 +133,10 @@ def apply_scatter(
             vals, idx, n, op, interpret=options.interpret_effective
         )
         return combine(op, prop_arr, reduced)
-    if options.shuffle and sort_perm is not None:
-        # conflict-free path: precomputed routing (sort) + segment reduce
-        reduced = segment_reduce(op, vals[sort_perm], idx[sort_perm], n, True)
+    if options.shuffle and dst_sorted:
+        # conflict-free path: the stream arrives dst-sorted (routing done
+        # once at bind time), so the commit is one sorted segment reduce
+        reduced = segment_reduce(op, vals, idx, n, True)
         # segment_min/max fill empty segments with identity of that reduce,
         # segment_sum fills 0 — all are the correct identities.
         return combine(op, prop_arr, reduced)
@@ -173,7 +180,7 @@ class KernelExec:
     state: Dict[str, jnp.ndarray]
     scalars: Dict[str, jnp.ndarray]
     graph_bind: Dict[str, Any]  # csr/csc arrays for neighbor loops
-    scatter_updates: List[Tuple[str, Optional[str], jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray], Optional[jnp.ndarray]]] = field(default_factory=list)
+    scatter_updates: List[Tuple[str, Optional[str], jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray], bool]] = field(default_factory=list)
     seq_writes: Dict[str, jnp.ndarray] = field(default_factory=dict)
 
     # -- property views -------------------------------------------------
@@ -401,13 +408,11 @@ class KernelExec:
             return
         # scattered / accumulator path
         idx = self.eval(idx_expr, lane)
-        # the precomputed shuffle routing is only valid when scattering
-        # along the edge kernel's destination lane in full-stream order
+        # sorted only along the edge kernel's destination lane of a
+        # dst-ordered full stream (compacted subsets bind dst_sorted=False)
         dst_sorted = (
-            self.kernel.kind is mir.KernelKind.EDGE
-            and isinstance(idx_expr, fir.Ident)
-            and idx_expr.name == self.kernel.dst_param
-            and lane.parent is None
+            self.graph_bind["dst_sorted"]
+            and _is_dst_lane(self.kernel, idx_expr, in_loop=lane.parent is not None)
         )
         self._scatter(prop, op, idx, val, lane, mask, dst_sorted=dst_sorted)
 
@@ -418,23 +423,66 @@ class KernelExec:
         wmask = mask
         if lane.valid is not None:
             wmask = lane.valid if wmask is None else jnp.logical_and(wmask, lane.valid)
-        sort_perm = self.graph_bind.get("dst_sort_perm") if dst_sorted else None
-        self.scatter_updates.append((prop, op, idx, val, wmask, sort_perm))
+        self.scatter_updates.append((prop, op, idx, val, wmask, dst_sorted))
 
     # -- commit ---------------------------------------------------------------
     def commit(self) -> Dict[str, jnp.ndarray]:
         out: Dict[str, jnp.ndarray] = {}
         out.update(self.seq_writes)
-        for prop, op, idx, val, wmask, sort_perm in self.scatter_updates:
+        for prop, op, idx, val, wmask, dst_sorted in self.scatter_updates:
             cur = out.get(prop, self.state[prop])
             out[prop] = apply_scatter(
-                cur, idx, val, wmask, op, sort_perm=sort_perm, options=self.options
+                cur, idx, val, wmask, op, dst_sorted=dst_sorted, options=self.options
             )
         return out
 
 
 class BackendError(Exception):
     pass
+
+
+def _is_dst_lane(kernel: mir.Kernel, idx_expr: fir.Expr, in_loop: bool) -> bool:
+    """A property write ``P[dst]`` on an edge kernel's own edge lane."""
+    return (
+        kernel.kind is mir.KernelKind.EDGE
+        and not in_loop
+        and isinstance(idx_expr, fir.Ident)
+        and idx_expr.name == kernel.dst_param
+    )
+
+
+def _scatters_off_dst(kernel: mir.Kernel, stmts, in_loop: bool = False) -> bool:
+    """Whether ``stmts`` hold a scattered property write off the dst lane."""
+    for st in stmts:
+        if isinstance(st, (fir.Assign, fir.ReduceAssign)):
+            t = st.target
+            if isinstance(t, fir.Index) and not _is_dst_lane(kernel, t.index, in_loop):
+                return True
+        elif isinstance(st, fir.If):
+            if (_scatters_off_dst(kernel, st.then_body, in_loop)
+                    or _scatters_off_dst(kernel, st.else_body or (), in_loop)):
+                return True
+        elif isinstance(st, fir.For) and _scatters_off_dst(kernel, st.body, True):
+            return True
+    return False
+
+
+def commits_presorted(kernel, options) -> bool:
+    """Whether a full-stream launch of ``kernel`` commits every scattered
+    write of its edge stages as a sorted segment reduction over the
+    dst-ordered stream, with no runtime permutation (what
+    ``EngineStats.presorted_launches`` counts). Decided at lowering: the
+    stream is dst-ordered exactly under ``shuffle``; ``pallas`` routes the
+    commit through its own kernel."""
+    if not options.shuffle or options.pallas:
+        return False
+    if isinstance(kernel, mir.PipelineKernel):
+        stages = kernel.edge_stages
+    else:
+        stages = [kernel] if kernel.kind is mir.KernelKind.EDGE else []
+    return bool(stages) and not any(
+        _scatters_off_dst(s, s.func.body) for s in stages
+    )
 
 
 def _binop(op: str, a, b):
@@ -539,6 +587,8 @@ class LoweredKernel:
     # batch lowering traces through. AOT-compiled executables cannot be
     # traced, so library-backed kernels MUST provide this.
     trace_full: Optional[Callable] = None
+    # a full launch commits with no runtime permutation (commits_presorted)
+    presorted: bool = False
 
 
 # graph-binding entries that are device arrays (as opposed to the static
@@ -547,7 +597,7 @@ class LoweredKernel:
 # shape bucket; all are int32, [E]-shaped except orig_id ([V]) and
 # logical_counts ([2]: unpadded |V|, |E| — what size() reports).
 GB_ARRAY_KEYS: Tuple[str, ...] = (
-    "order", "src", "dst", "dst_sort_perm",
+    "order", "src", "dst",
     "csr_row_pos", "csr_indices", "csr_eids",
     "csc_row_pos", "csc_indices", "csc_eids",
     "orig_id", "logical_counts",
@@ -617,7 +667,10 @@ def _graph_bindings(
 
     ``options`` is a :class:`~repro.core.target.Target` (or a legacy
     CompileOptions through the compat shim — both expose the substrate
-    attributes read here).
+    attributes read here). Under ``shuffle`` the stream is the burst order
+    stably sorted by dst: partitions stay contiguous, each destination's
+    edges keep ascending src, and a dst-lane commit needs no permutation
+    (``dst_sorted``). ``order`` maps stream position -> original edge id.
     """
     if options.burst:
         auto = getattr(options, "auto_partitions", None)
@@ -629,9 +682,11 @@ def _graph_bindings(
         order = pe.edge_order
     else:
         order = np.arange(g.n_edges, dtype=np.int32)
+    dst_sorted = bool(options.shuffle)
+    if dst_sorted:
+        order = order[np.argsort(g.dst[order], kind="stable")]
     src_o = g.src[order]
     dst_o = g.dst[order]
-    dst_sort = np.argsort(dst_o, kind="stable").astype(np.int32)
 
     indptr, csr_idx, csr_eids = g.csr
     in_indptr, csc_idx, csc_eids = g.csc
@@ -640,10 +695,10 @@ def _graph_bindings(
     gb = {
         "n_vertices": g.n_vertices,
         "n_edges": g.n_edges,
+        "dst_sorted": dst_sorted,
         "order": jnp.asarray(order),
         "src": jnp.asarray(src_o),
         "dst": jnp.asarray(dst_o),
-        "dst_sort_perm": jnp.asarray(dst_sort),
         "csr_row_pos": jnp.asarray(row_ids),
         "csr_indices": jnp.asarray(csr_idx),
         "csr_eids": jnp.asarray(csr_eids),
@@ -728,6 +783,7 @@ def lower_pipeline(
         pipeline.name, mir.KernelKind.PIPELINE,
         run_full=gt_jit(run_full, pipeline.name + "_full"),
         trace_full=run_full,
+        presorted=gb["dst_sorted"] and commits_presorted(pipeline, options),
     )
 
 
@@ -765,8 +821,8 @@ def lower_kernel(
 
         def run_subset(state, scalars, batch):
             src, dst, w, eid, valid = batch
-            # subsets are unsorted: disable the static shuffle permutation
-            sub_gb = dict(gb, dst_sort_perm=None)
+            # compacted subsets are in frontier order, not dst order
+            sub_gb = dict(gb, dst_sorted=False)
             ex = KernelExec(module, kernel, options, state, scalars, sub_gb)
             bindings = {kernel.src_param: src, kernel.dst_param: dst, "edge": eid}
             if kernel.weight_param is not None:
@@ -786,6 +842,7 @@ def lower_kernel(
             run_subset=gt_jit(run_subset, kernel.name + "_subset"),
             frontier=kernel.frontier,
             trace_full=run_full,
+            presorted=gb["dst_sorted"] and commits_presorted(kernel, options),
         )
 
     # vertex kernel
@@ -841,6 +898,7 @@ class GenericLoweredKernel:
     # state/scalars over the query axis. Living here (not per engine) is
     # what lets same-bucket rebinds reuse the batched XLA traces too.
     jit_batched: Optional[Callable] = None
+    presorted: bool = False  # commits_presorted(kernel, target)
 
 
 def lower_kernel_generic(
@@ -851,7 +909,11 @@ def lower_kernel_generic(
     target,
 ) -> GenericLoweredKernel:
     """Lower one kernel with graph bindings as arguments (shape-generic)."""
-    statics = {"n_vertices": n_vertices, "n_edges": n_edges}
+    # the bound arrays follow the Burst Read plan of this target, which
+    # streams edges in dst order exactly under shuffle
+    statics = {"n_vertices": n_vertices, "n_edges": n_edges,
+               "dst_sorted": bool(target.shuffle)}
+    presorted = commits_presorted(kernel, target)
 
     if isinstance(kernel, mir.PipelineKernel):
         stages = list(kernel.stages)
@@ -869,6 +931,7 @@ def lower_kernel_generic(
         return GenericLoweredKernel(
             kernel.name, mir.KernelKind.PIPELINE, raw_full,
             gt_jit(raw_full, kernel.name + "_full"),
+            presorted=presorted,
         )
 
     if kernel.kind is mir.KernelKind.EDGE:
@@ -880,8 +943,8 @@ def lower_kernel_generic(
 
         def raw_subset(gba, state, scalars, batch):
             src, dst, w, eid, valid = batch
-            # subsets are unsorted: disable the static shuffle permutation
-            sub_gb = dict(gba, **statics, dst_sort_perm=None)
+            # compacted subsets are in frontier order, not dst order
+            sub_gb = {**gba, **statics, "dst_sorted": False}
             ex = KernelExec(module, kernel, target, state, scalars, sub_gb)
             bindings = {kernel.src_param: src, kernel.dst_param: dst, "edge": eid}
             if kernel.weight_param is not None:
@@ -900,6 +963,7 @@ def lower_kernel_generic(
             gt_jit(raw_full, kernel.name + "_full"),
             jit_subset=gt_jit(raw_subset, kernel.name + "_subset"),
             frontier=kernel.frontier,
+            presorted=presorted,
         )
 
     # vertex kernel

@@ -504,6 +504,7 @@ class DistEngine(Engine):
         stage_fns = []
         n_dist = 0
         n_local_edges = 0
+        n_presorted = 0
         for stage in kern.stages:
             entry = entries.get(stage.name)
             if entry is not None:
@@ -518,6 +519,8 @@ class DistEngine(Engine):
                 stage_fns.append(lambda s, sc, buckets, f=vstage: f(s, sc))
                 if stage.kind is mir.KernelKind.EDGE:
                     n_local_edges += 1
+                    n_presorted += (gb["dst_sorted"]
+                                    and backend.commits_presorted(stage, options))
 
         def run(state, scalars, buckets):
             cur = dict(state)
@@ -533,6 +536,7 @@ class DistEngine(Engine):
         def bump(stats):
             stats.dist_supersteps += n_dist
             stats.full_launches += len(stage_fns) - n_dist
+            stats.presorted_launches += n_presorted
             stats.edges_traversed += n_edges * (n_dist + n_local_edges)
 
         return BatchedLaunch(

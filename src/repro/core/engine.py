@@ -56,6 +56,10 @@ class EngineStats:
     kernel_launches: Dict[str, int] = field(default_factory=dict)
     compacted_launches: int = 0
     full_launches: int = 0
+    # full-stream edge launches whose every scattered write committed over
+    # the dst-ordered stream with no runtime permutation
+    # (backend.commits_presorted); never a compacted launch
+    presorted_launches: int = 0
     dist_supersteps: int = 0
     edges_traversed: int = 0
     host_iterations: int = 0
@@ -407,11 +411,11 @@ class Engine:
                 fn = backend.lower_kernel_batched(self._kernel(name))
             bl = self._batched[name] = BatchedLaunch(
                 fn=fn,
-                bump_stats=self._full_stats_bump(kern),
+                bump_stats=self._full_stats_bump(kern, self._kernel(name).presorted),
             )
         return bl
 
-    def _full_stats_bump(self, kern) -> Callable[[EngineStats], None]:
+    def _full_stats_bump(self, kern, presorted: bool) -> Callable[[EngineStats], None]:
         """Stats increment matching one full-stream launch of ``kern``."""
         n_edges = self.graph.n_edges
         if kern.kind is mir.KernelKind.EDGE:
@@ -423,6 +427,7 @@ class Engine:
 
         def bump(stats: EngineStats) -> None:
             stats.full_launches += 1
+            stats.presorted_launches += presorted
             stats.edges_traversed += edges
 
         return bump
@@ -447,6 +452,7 @@ class Engine:
             if launched:
                 return
         self.stats.full_launches += 1
+        self.stats.presorted_launches += lk.presorted
         edges = 0
         if kern.kind is mir.KernelKind.EDGE:
             edges = self.graph.n_edges

@@ -64,7 +64,9 @@ class Target:
 
     * ``burst`` — partitioned, ascending-src streaming order.
     * ``cache`` — hub-vertex relabeling (dense VMEM-prefix hub cache).
-    * ``shuffle`` — dst-binned sorted segment reduction (conflict-free).
+    * ``shuffle`` — stream edges in dst order (the burst order stably
+      sorted by dst) and commit dst-lane writes as sorted segment
+      reductions, with no runtime permutation (conflict-free).
     * ``compact_frontier`` — only traverse active edges when the frontier
       is small (direction optimization).
     * ``pallas`` — route scatter-reduce/gather through Pallas TPU kernels.

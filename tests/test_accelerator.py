@@ -23,9 +23,11 @@ import repro
 from repro.algorithms import sources
 from repro.core import CompileOptions, Target
 from repro.core.accelerator import (
+    ARTIFACT_FORMAT,
     AcceleratorError,
     GraphShape,
     accelerator_fingerprint,
+    load_or_lower,
 )
 from repro.graph import generators
 
@@ -286,7 +288,8 @@ def test_save_without_executables_relowers(graph, tmp_path):
     _assert_results_equal(acc.bind(graph).run(), loaded.bind(graph).run())
 
 
-def test_load_rejects_stale_artifact(graph, tmp_path):
+@pytest.mark.parametrize("stale_format", [ARTIFACT_FORMAT - 1, 999])
+def test_load_rejects_stale_artifact(graph, tmp_path, stale_format):
     import json
     import os
 
@@ -305,11 +308,28 @@ def test_load_rejects_stale_artifact(graph, tmp_path):
     mpath = os.path.join(path, "manifest.json")
     with open(mpath) as f:
         manifest = json.load(f)
-    manifest["format"] = 999
+    manifest["format"] = stale_format
     with open(mpath, "w") as f:
         json.dump(manifest, f)
     with pytest.raises(AcceleratorError, match="format"):
         repro.load_accelerator(path)
+    # an artifact store re-lowers a stale-format artifact at its key
+    prog, shape = acc.program, acc.shape
+    store = str(tmp_path / "store")
+    keyed = os.path.join(
+        store, accelerator_fingerprint(prog.fingerprint, acc.target, shape)[:24])
+    acc.save(keyed)
+    with open(os.path.join(keyed, "manifest.json")) as f:
+        manifest = json.load(f)
+    manifest["format"] = stale_format
+    with open(os.path.join(keyed, "manifest.json"), "w") as f:
+        json.dump(manifest, f)
+    relowered, loaded, _ = load_or_lower(prog, acc.target, shape, store)
+    assert not loaded
+    _assert_results_equal(acc.bind(graph).run(root=7),
+                          relowered.bind(graph).run(root=7))
+    with open(os.path.join(keyed, "manifest.json")) as f:
+        assert json.load(f)["format"] == ARTIFACT_FORMAT
 
 
 def test_accelerator_fingerprint_is_content_keyed(graph):
