@@ -23,6 +23,12 @@ draws each query's ``root``):
   graph before the window, so nothing a query leaves behind can answer
   it. One more root, never sent in the window, goes to the graph itself
   so that it is bound.
+- With ``roots.warmup`` ``"spare"`` the warm-up instead sends
+  ``roots.warmup_count`` spare roots, drawn from the seed and never sent
+  in the window, to the graph itself, and binds no relabeled copy (a
+  second graph in device memory, and a second bind). The window may then
+  meet a frontier shape the warm-up missed; ``compile_s_in_window`` shows
+  it.
 - Without ``roots`` every query has the same parameters, and the warm-up
   sends one of them.
 """
@@ -37,10 +43,10 @@ from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional
 
 import numpy as np
 
+from bench.arcs import LANES
+
 #: numpy stream ids under the run's seed (the graph uses jax.random)
 ROOT_STREAM, RELABEL_STREAM = 1, 2
-#: roots traversed together by ``dense_levels`` (one bit of a word each)
-LANES = 64
 
 
 @dataclass
@@ -77,31 +83,16 @@ def dense_levels(arcs, roots, share: float) -> np.ndarray:
     """For each root (at most ``LANES``), how many levels of a BFS from it
     have a frontier whose out-arcs exceed ``share`` of all arcs.
 
-    One bit-parallel traversal serves all roots: bit ``j`` of a vertex's
-    word says it is in root ``j``'s frontier."""
-    roots = np.asarray(roots, np.int64)
-    k = roots.size
-    if not 0 < k <= LANES or np.unique(roots).size != k:
-        raise ValueError("dense_levels takes 1 to 64 distinct roots")
-    indptr, sources = arcs.in_adjacency
-    has_in = np.diff(indptr) > 0
-    starts = indptr[:-1][has_in]
+    One bit-parallel traversal serves all roots (``Arcs.frontiers``)."""
+    k = len(roots)
     deg = arcs.out_degree.astype(np.float64)
-    frontier = np.zeros(arcs.n, np.uint64)
-    frontier[roots] = np.uint64(1) << np.arange(k, dtype=np.uint64)
-    seen = frontier.copy()
     counts = np.zeros(k, np.int64)
-    while True:
+    for frontier in arcs.frontiers(roots):
         active = np.flatnonzero(frontier)
-        if active.size == 0:
-            return counts
         bits = np.unpackbits(frontier[active].view(np.uint8).reshape(-1, 8),
                              axis=1, bitorder="little")[:, :k]
         counts += deg[active] @ bits > share * arcs.m
-        nxt = np.zeros_like(frontier)
-        nxt[has_in] = np.bitwise_or.reduceat(frontier[sources], starts)
-        frontier = nxt & ~seen
-        seen |= frontier
+    return counts
 
 
 def _stratified(order, arcs, strata: dict, count: int,
@@ -151,12 +142,23 @@ def plan(traffic: dict, arcs, seed: int) -> Plan:
     if len(chosen) < count or order.size <= count:
         raise ValueError(f"the graph has too few roots for {count}")
     taken = set(chosen)
-    spare = next(int(r) for r in order if int(r) not in taken)
+    spares = (int(r) for r in order if int(r) not in taken)
+    window = itertools.cycle([{**base, "root": r} for r in chosen])
+    if "warmup" in roots:
+        if roots["warmup"] != "spare":
+            raise ValueError(f"unknown warm-up rule {roots['warmup']!r}")
+        n = int(roots["warmup_count"])
+        if n < 1:
+            raise ValueError("roots.warmup_count is at least 1")
+        warm = list(itertools.islice(spares, n))
+        if len(warm) < n:
+            raise ValueError(f"the graph has too few roots for {count} "
+                             f"and {n} spares")
+        return Plan([{**base, "root": r} for r in warm], window)
+    spare = next(spares)
     relabel = np.random.default_rng([seed, RELABEL_STREAM]).permutation(
         arcs.n).astype(np.int32)
-    return Plan([{**base, "root": spare}],
-                itertools.cycle([{**base, "root": r} for r in chosen]),
-                relabel=relabel,
+    return Plan([{**base, "root": spare}], window, relabel=relabel,
                 warmup_relabeled=[{**base, "root": int(relabel[r])}
                                   for r in chosen])
 
@@ -165,7 +167,8 @@ def drive(submit: Callable[[Dict[str, Any]], Any], queries: Iterable[dict],
           until: Optional[float] = None,
           keep: Callable[[Any], Any] = lambda result: result,
           annotate: Callable[[str], Any] = None,
-          late_s: Optional[float] = None) -> List[Query]:
+          late_s: Optional[float] = None,
+          stop_on_error: bool = False) -> List[Query]:
     """Send queries from one closed-loop client, each as soon as the last
     one has returned, until the ``time.perf_counter`` reading ``until``
     (or, with ``until`` None, until ``queries`` runs out).
@@ -175,7 +178,8 @@ def drive(submit: Callable[[Dict[str, Any]], Any], queries: Iterable[dict],
     ``annotate(name)`` gives a context manager around each submit and each
     wait (host spans in the profiler's trace). With ``late_s``, an answer
     is awaited until that long past ``until``, and a query still
-    unanswered then is left unsettled, which ends the sending."""
+    unanswered then is left unsettled, which ends the sending. With
+    ``stop_on_error`` the first query that fails ends the sending."""
     annotate = annotate or (lambda name: _NULL)
     sent: List[Query] = []
     for params in queries:
@@ -190,6 +194,8 @@ def drive(submit: Callable[[Dict[str, Any]], Any], queries: Iterable[dict],
             except Exception as exc:  # refused at admission: counted as failed
                 qy.t_done, qy.error = time.perf_counter(), exc
                 qy.settled.set()
+                if stop_on_error:
+                    break
                 continue
             fut.add_done_callback(functools.partial(_settle, qy, keep))
         with annotate("bench.wait"):
@@ -197,6 +203,8 @@ def drive(submit: Callable[[Dict[str, Any]], Any], queries: Iterable[dict],
                      else max(0.0, until + late_s - time.perf_counter()))
             if not qy.settled.wait(limit):
                 break
+        if stop_on_error and qy.error is not None:
+            break
     return sent
 
 

@@ -8,11 +8,12 @@ configuration, ``bench/configs/<config>.json`` (the graph), and a traffic
 mix, ``bench/traffic/<traffic>.json``. The run:
 
 1. draws the graph from ``--seed`` with ``bench/graphs/<generator>.py`` and
-   starts the service with its own settings, ``repro.serve(<artifact
-   store>)``;
+   starts the service on the cell's chips: on one chip with the service's
+   own settings, ``repro.serve(<artifact store>)``; on several, on the
+   distributed backend over a mesh of exactly those chips;
 2. warms up: sends the mix's warm-up queries through the same path (for a
    mix with roots, most of them to a relabeled copy of the graph; see
-   ``bench/load.py``);
+   ``bench/load.py``). The first warm-up query that fails ends the run;
 3. measures for ``--seconds``: one closed-loop client sends queries
    through ``GraphService.submit`` and times each from submit to result;
 4. reads the device's peak memory and closes the service;
@@ -27,7 +28,9 @@ telemetry, and the metrics are the per-layer ones, each read by
 Set-up phases go to standard output as they end; the last line of
 standard output is the result, one JSON object. Each number compared and
 its limit end standard error. A host where JAX finds no TPU, or fewer
-chips than the cell asks for, exits 1 and prints no result.
+chips than the cell asks for, a failed warm-up query, and set-up that
+outlasts ``SETUP_LIMIT_S`` from process start each exit 1 with the cause
+on standard error, and print no result.
 """
 from __future__ import annotations
 
@@ -43,6 +46,8 @@ import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+import threading  # noqa: E402
+import traceback  # noqa: E402
 from dataclasses import dataclass, field  # noqa: E402
 from pathlib import Path  # noqa: E402
 from typing import Any, Dict, List, Optional  # noqa: E402
@@ -62,11 +67,54 @@ COMPILE_CACHE = BENCH / ".jax_cache"
 ARTIFACT_STORE = BENCH / ".store"
 #: how long past the window's close an answer is still awaited
 LATE_S = 60.0
+#: the longest set-up, from process start to the window's open: a run's
+#: 1200 s allowance less the longest window (51 s), ``LATE_S``, the answer
+#: checks and the trace reduction, with room to spare
+SETUP_LIMIT_S = 800.0
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
 def log(msg: str) -> None:
     print(msg, flush=True)
+
+
+class SetupFailed(RuntimeError):
+    """Set-up cannot reach the window; the run ends with exit 1."""
+
+
+class SetupClock:
+    """Names the set-up phase that is running, and once started, ends the
+    process with exit 1 when set-up outlasts ``limit_s`` from ``t0``.
+
+    It has to end the process by ``os._exit``: a bind inside a service
+    worker cannot be interrupted."""
+
+    def __init__(self, limit_s: float, t0: float = T_START) -> None:
+        self.phase = "start-up"
+        self.limit_s = limit_s
+        self._deadline = t0 + limit_s
+        self._open = threading.Event()
+
+    def start(self) -> "SetupClock":
+        threading.Thread(target=self._watch, name="bench-setup-clock",
+                         daemon=True).start()
+        return self
+
+    def enter(self, phase: str) -> None:
+        self.phase = phase
+
+    def stop(self) -> None:
+        """The window opens: set-up is over."""
+        self._open.set()
+
+    def _watch(self) -> None:
+        if self._open.wait(max(0.0, self._deadline - time.perf_counter())):
+            return
+        sys.stdout.flush()
+        sys.stderr.write(f"bench: set-up passed {self.limit_s:g} s in "
+                         f"{self.phase}\n")
+        sys.stderr.flush()
+        os._exit(1)
 
 
 # ---------------------------------------------------------------------------
@@ -184,24 +232,63 @@ def quantile(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
+def start_service(store: Path, devices: list):
+    """The service over exactly ``devices``. One chip runs the service as
+    it comes, ``repro.serve(<store>)``; several run its distributed
+    backend, on a mesh pinned to their count where the host shows more."""
+    import repro
+
+    if len(devices) == 1:
+        return repro.serve(str(store))
+    import jax
+
+    pin = jax.device_count() > len(devices)
+    target = repro.Target(kind="distributed",
+                          n_devices=len(devices) if pin else 0)
+    mesh = list(target.mesh().devices.flat)
+    if len(mesh) != len(devices) or set(mesh) != set(devices):
+        raise SetupFailed(f"the distributed target's mesh holds {mesh}, "
+                          f"the cell's chips are {devices}")
+    if pin:
+        return repro.serve(str(store), backend="distributed", target=target)
+    return repro.serve(str(store), backend="distributed")
+
+
+def warm_up(submit, queries: List[dict], keep, what: str) -> List[load.Query]:
+    """Send the warm-up ``queries``; the first one that fails ends set-up."""
+    sent = load.drive(submit, queries, keep=keep, stop_on_error=True)
+    for q in sent:
+        if q.error is not None:
+            traceback.print_exception(q.error, file=sys.stderr)
+            raise SetupFailed(f"{what} query {q.index} {q.params} failed: "
+                              f"{q.error!r}")
+    return sent
+
+
 def run_cell(workload: str, config: dict, traffic: dict, e2e: List[dict],
              per_layer: List[dict], seed: int, seconds: float, trace: bool,
-             devices: list, store: Path = ARTIFACT_STORE) -> dict:
-    """One run of one cell on ``devices``; returns the result object."""
+             devices: list, store: Path = ARTIFACT_STORE,
+             clock: Optional[SetupClock] = None) -> dict:
+    """One run of one cell on ``devices``; returns the result object.
+
+    ``clock`` is told each set-up phase as it starts, and stopped when the
+    window opens."""
     import jax
-    import repro
     import repro.telemetry as tel
     from repro.graph.storage import GraphData
 
+    clock = clock or SetupClock(SETUP_LIMIT_S)
     algorithm = traffic["algorithm"]
     ref = load_module("refs", algorithm)
 
+    clock.enter("generate")
     t = time.perf_counter()
     gen = load_module("graphs", config["graph"]["generator"])
     arcs = gen.generate(config["graph"], seed)
     log(f"setup generate: {time.perf_counter() - t:.3f}s "
         f"(|V|={arcs.n} arcs={arcs.m})")
     graph = GraphData(arcs.n, arcs.src, arcs.dst)
+    clock.enter("plan")
     t = time.perf_counter()
     queries = load.plan(traffic, arcs, seed)
     log(f"setup plan: {time.perf_counter() - t:.3f}s")
@@ -209,31 +296,29 @@ def run_cell(workload: str, config: dict, traffic: dict, e2e: List[dict],
     def keep(result) -> Answer:
         return Answer(np.asarray(result.properties[ref.ANSWER]), result.stats)
 
-    svc = repro.serve(str(store))
+    clock.enter("warm-up")
+    svc = start_service(store, devices)
     try:
         def submitter(g):
             return lambda params: svc.submit(algorithm, g, **params)
 
         submit = submitter(graph)
         t = time.perf_counter()
-        warm = load.drive(submit, queries.warmup, keep=keep)
+        warm = warm_up(submit, queries.warmup, keep, "warm-up")
         log(f"setup warm-up: {time.perf_counter() - t:.3f}s for {len(warm)} "
             f"queries (first, with bind and lowering: "
             f"{warm[0].latency_s:.3f}s)")
         if queries.relabel is not None:
+            clock.enter("warm-up on the relabeled copy")
             t = time.perf_counter()
             twin = GraphData(arcs.n, queries.relabel[arcs.src],
                              queries.relabel[arcs.dst])
-            more = load.drive(submitter(twin), queries.warmup_relabeled,
-                              keep=keep)
+            more = warm_up(submitter(twin), queries.warmup_relabeled, keep,
+                           "warm-up on the relabeled copy")
             log(f"setup warm-up on the relabeled copy: "
                 f"{time.perf_counter() - t:.3f}s for {len(more)} queries "
                 f"(first, with bind: {more[0].latency_s:.3f}s)")
-            warm += more
             del twin
-        bad = [q for q in warm if q.error is not None]
-        if bad:
-            raise RuntimeError(f"warm-up query failed: {bad[0].error!r}")
         reg = svc.stats()["registry"]
         log(f"setup registry: lowerings={reg['lowerings']} "
             f"artifact_hits={reg['artifact_hits']} "
@@ -247,6 +332,7 @@ def run_cell(workload: str, config: dict, traffic: dict, e2e: List[dict],
             jax.profiler.start_trace(trace_dir)
         annotate = jax.profiler.TraceAnnotation if trace else None
 
+        clock.stop()
         setup_s = time.perf_counter() - T_START
         t0 = time.perf_counter()
         t1 = t0 + seconds
@@ -278,7 +364,8 @@ def run_cell(workload: str, config: dict, traffic: dict, e2e: List[dict],
         s = q.result.stats
         log(f"query {q.index} {q.params}: {q.latency_s:.4f}s batch "
             f"{s.batch_size} launches {s.total_launches} (compacted "
-            f"{s.compacted_launches}, full {s.full_launches}) compile "
+            f"{s.compacted_launches}, full {s.full_launches}, supersteps "
+            f"{s.dist_supersteps}) compile "
             f"{s.compile_time_s:.3f}s")
 
     checked, gap = check_answers(ref, arcs, sent)
@@ -369,17 +456,22 @@ def completed_share(sent: List[load.Query], t1: float) -> float:
 def check_answers(ref, arcs, sent: List[load.Query]):
     """Compare every answer the window's queries got with the host
     reference, once per group of equal ``ref.key`` (equal keys, equal
-    answers). Returns (answers compared, the largest ``ref.gap``)."""
+    answers), by ``ref.reference_batch`` where the reference has one.
+    Returns (answers compared, the largest ``ref.gap``)."""
     import jax
 
     groups: Dict[Any, List[load.Query]] = {}
     for q in sent:
         if q.error is None and q.settled.is_set():
             groups.setdefault(ref.key(q.params), []).append(q)
+    params = [qs[0].params for qs in groups.values()]
     worst, checked = 0.0, 0
     with jax.profiler.TraceAnnotation("bench.check"):
-        for qs in groups.values():
-            want = ref.reference(arcs, qs[0].params)
+        if hasattr(ref, "reference_batch"):
+            wants = ref.reference_batch(arcs, params)
+        else:
+            wants = (ref.reference(arcs, p) for p in params)
+        for qs, want in zip(groups.values(), wants):
             for q in qs:
                 worst = max(worst, ref.gap(q.result.answer, want))
                 checked += 1
@@ -403,6 +495,24 @@ def configure_compile_cache() -> str:
     return path
 
 
+def cell_devices(workload: str, chips: int) -> Optional[list]:
+    """The first ``chips`` TPU chips that JAX sees, or None, with the
+    cause on standard error, where there are fewer or none."""
+    import jax
+
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != "tpu":
+        print(f"bench: JAX found no TPU (platform {platform!r}); nothing "
+              f"runs in its place", file=sys.stderr)
+        return None
+    if len(devices) < chips:
+        print(f"bench: {workload} needs {chips} chips, JAX sees "
+              f"{len(devices)}", file=sys.stderr)
+        return None
+    return devices[:chips]
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -410,26 +520,22 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
+    clock = SetupClock(SETUP_LIMIT_S).start()
 
     cell, config, traffic, e2e, per_layer = resolve_cell(
         load_manifest(), args.workload)
-    import jax
-
     log(f"compile cache: {configure_compile_cache()}")
-    devices = jax.devices()
-    platform = devices[0].platform
-    if platform != "tpu":
-        print(f"bench: JAX found no TPU (platform {platform!r}); nothing "
-              f"runs in its place", file=sys.stderr)
+    devices = cell_devices(args.workload, int(cell["chips"]))
+    if devices is None:
         return 1
-    if len(devices) < int(cell["chips"]):
-        print(f"bench: {args.workload} needs {cell['chips']} chips, JAX sees "
-              f"{len(devices)}", file=sys.stderr)
-        return 1
-    devices = devices[:int(cell["chips"])]
     log(f"device: {devices[0].device_kind} x{len(devices)}")
-    out = run_cell(args.workload, config, traffic, e2e, per_layer, args.seed,
-                   args.seconds, bool(args.trace), devices)
+    try:
+        out = run_cell(args.workload, config, traffic, e2e, per_layer,
+                       args.seed, args.seconds, bool(args.trace), devices,
+                       clock=clock)
+    except SetupFailed as exc:
+        print(f"bench: {exc}", file=sys.stderr, flush=True)
+        return 1
     for name, c in out["checks"].items():
         print(f"check {name}: {c['value']} (limit {c['limit']})",
               file=sys.stderr, flush=True)

@@ -1,6 +1,9 @@
 """The traffic generator without the program: who sends which query, when
 the window closes, and what the warm-up holds."""
 import concurrent.futures
+import hashlib
+import itertools
+import json
 import time
 
 import numpy as np
@@ -156,3 +159,74 @@ def test_rate_counts_the_running_query_by_its_share():
     # two done by t1 = 10, and half of the third's 4 s fell inside
     assert run.completed_share(sent, 10.0) == pytest.approx(2.5)
     assert run.completed_share(sent, 12.0) == pytest.approx(3.0)
+
+
+# sha256 of each committed mix's plan (warm-up, first 90 window queries,
+# relabeling) on its configuration cut to scale 11, as the harness drew
+# them before the spare warm-up rule existed
+PLAN_DIGESTS = {
+    ("r19.bfs.serial", 7):
+        "66b945c82b839ee81ed9d75b53c0ffa43ed4fef5ffbe322f3f617d60c82f3ef3",
+    ("r19.bfs.serial", 2**40 + 3):
+        "8bc9e7495f134ab25ab3ba7e98b86114979c120f4a66eb3feeed96e852afb9e1",
+    ("g500s18.pagerank.serial", 7):
+        "d38976b14da92468bf0e908cf66e77cbbe3d91c86d49f12ee348cdd8979a0924",
+    ("g500s18.pagerank.serial", 2**40 + 3):
+        "d38976b14da92468bf0e908cf66e77cbbe3d91c86d49f12ee348cdd8979a0924",
+}
+
+
+@pytest.mark.parametrize("workload,seed", sorted(PLAN_DIGESTS))
+def test_committed_mixes_plan_the_same_queries(workload, seed):
+    from bench import run
+
+    _, config, traffic, _, _ = run.resolve_cell(run.load_manifest(), workload)
+    g = kronecker.generate(dict(config["graph"], scale=11), 2**33 + 21)
+    p = load.plan(traffic, g, seed)
+    doc = {"warmup": p.warmup, "window": list(itertools.islice(p.window, 90)),
+           "relabel": None if p.relabel is None else p.relabel.tolist(),
+           "warmup_relabeled": p.warmup_relabeled}
+    digest = hashlib.sha256(json.dumps(doc, sort_keys=True).encode())
+    assert digest.hexdigest() == PLAN_DIGESTS[workload, seed]
+
+
+def test_spare_warmup_goes_to_the_graph_itself():
+    t = {"params": {"x": 1},
+         "roots": {"rule": "out_degree_positive", "count": 4,
+                   "warmup": "spare", "warmup_count": 3}}
+    p = load.plan(t, ARCS, 7)
+    window = [next(p.window)["root"] for _ in range(4)]
+    spares = [q["root"] for q in p.warmup]
+    assert len(set(spares)) == 3 and not set(spares) & set(window)
+    assert all(DEG[r] > 0 and q["x"] == 1 for r, q in zip(spares, p.warmup))
+    assert p.relabel is None and p.warmup_relabeled == []
+    # the window is the default rule's, and the first spare its spare
+    default = load.plan({"params": {"x": 1},
+                         "roots": {"rule": "out_degree_positive",
+                                   "count": 4}}, ARCS, 7)
+    assert [next(default.window)["root"] for _ in range(4)] == window
+    assert default.warmup[0]["root"] == spares[0]
+    assert load.plan(t, ARCS, 7).warmup == p.warmup
+    for bad in ({"warmup_count": 6}, {"warmup_count": 0},
+                {"warmup": "relabel"}):
+        with pytest.raises(ValueError):
+            load.plan({"roots": {**t["roots"], **bad}}, ARCS, 7)
+
+
+def test_warm_up_stops_at_the_first_failure():
+    calls = []
+
+    def submit(params):
+        calls.append(params["i"])
+        f = concurrent.futures.Future()
+        if params["i"] == 2:
+            f.set_exception(RuntimeError("bind failed"))
+        else:
+            f.set_result(params["i"])
+        return f
+
+    qs = load.drive(submit, ({"i": i} for i in range(10)), stop_on_error=True)
+    assert calls == [0, 1, 2] and isinstance(qs[-1].error, RuntimeError)
+    calls.clear()
+    qs = load.drive(submit, ({"i": i} for i in range(10)))
+    assert calls == list(range(10)) and qs[2].error is not None

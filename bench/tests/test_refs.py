@@ -70,3 +70,19 @@ def test_gap_of_a_malformed_answer():
     assert ref_bfs.gap(np.array([1, 2]), want) == 3
     assert ref_pr.gap(np.array([np.nan, 1.0, 1.0]),
                       np.array([1.0, 1.0, 1.0])) == float("inf")
+
+
+@pytest.mark.parametrize("undirected", [False, True])
+@pytest.mark.parametrize("k", [1, 63, 64, 65])
+def test_bfs_batched_reference_equals_scipy(undirected, k):
+    spec = dict(SPEC, scale=9, edge_factor=4, undirected=undirected)
+    g = kronecker.generate(spec, 2**33 + 11 + undirected)
+    roots = np.random.default_rng(k).choice(g.n, k, replace=False)
+    params = [{"root": int(r)} for r in roots]
+    got = list(ref_bfs.reference_batch(g, params))
+    assert len(got) == k
+    want = [ref_bfs.reference(g, p) for p in params]
+    for p, a, b in zip(params, got, want):
+        assert a.dtype == np.int32 and a[p["root"]] == 1
+        assert np.array_equal(a, b) and ref_bfs.gap(a, b) == 0
+    assert (np.concatenate(want) == -1).any()  # some vertices unreached
